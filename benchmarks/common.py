@@ -92,7 +92,10 @@ def emit(
     # memory stamp: RSS high-water + live device buffers at emit time, so
     # every BENCH_*.json row carries the footprint alongside the timing
     row.setdefault("peak_rss_bytes", peak_rss_bytes())
-    row.setdefault("device_bytes", device_buffer_bytes())
+    if "device_bytes" not in row:
+        # only read when the row lacks it: reading starts a JAX backend, which
+        # a parent of per-point JAX children (bench_streaming) must not hold
+        row["device_bytes"] = device_buffer_bytes()
     _rows.append(row)
     print(f"{name},{us_per_call:.1f},{derived}", flush=True)
 
@@ -128,16 +131,13 @@ def span_stats(durations_s: List[float]) -> Dict[str, float]:
 def timeit_stats(fn: Callable, *args, repeats: int = 3, **kw) -> Dict[str, float]:
     """Time ``fn(*args, **kw)`` via tracer spans: one span per repeat, device
     work forced complete inside each span.  Returns best/mean/std in µs."""
+    import jax
+
     tracer = Tracer()
 
     def once():
-        out = fn(*args, **kw)
-        try:
-            import jax
-
-            jax.block_until_ready(out)
-        except Exception:
-            pass
+        # a failed wait must raise: swallowing it would time the enqueue only
+        jax.block_until_ready(fn(*args, **kw))
 
     once()  # warmup / compile
     for _ in range(repeats):

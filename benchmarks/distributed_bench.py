@@ -1,7 +1,7 @@
 """Mesh engine comm accounting: the paper's 1/T claim in compiled HLO.
 
-Runs ``MeshSyncEngine`` over {1, 2, 4, 8} virtual devices (subprocess with
-``--xla_force_host_platform_device_count=8``) and reports, per mesh size,
+Runs ``MeshSyncEngine`` over {1, 2, 4, 8} virtual devices (a CPU-only
+subprocess with ``--xla_force_host_platform_device_count=8``) and reports, per mesh size,
 trajectory parity against the single-device ``BatchedSyncEngine`` and the
 ``MeshCommLedger`` HLO collective-byte readings; then sweeps T
 (edge rounds per cloud round) at the full mesh and checks the structural
@@ -95,20 +95,18 @@ def _run() -> None:
     root = os.path.join(os.path.dirname(__file__), "..")
     src = os.path.join(root, "src")
     ks, ts = ((1, 8), (1, 4)) if QUICK else ((1, 2, 4, 8), (1, 2, 4))
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join((src, root)))
+    # the child is a CPU accounting tool on virtual devices: it never
+    # contends with a parent that holds an accelerator
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join((src, root)), JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     code = _CODE % {"ks": repr(tuple(ks)), "ts": repr(tuple(ts))}
-    try:
-        res = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=1500)
-        if res.returncode != 0:
-            emit("distributed_mesh", 0.0,
-                 "FAILED: " + res.stderr.strip().splitlines()[-1][:120])
-            return
-        data = json.loads(res.stdout.strip().splitlines()[-1])
-    except Exception as e:  # noqa: BLE001
-        emit("distributed_mesh", 0.0, f"FAILED: {e}")
-        return
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=1500)
+    if res.returncode != 0:
+        emit("distributed_mesh", 0.0,
+             "FAILED: " + res.stderr.strip().splitlines()[-1][:120])
+        raise RuntimeError(f"distributed_mesh child failed:\n{res.stderr[-3000:]}")
+    data = json.loads(res.stdout.strip().splitlines()[-1])
     bad = []
     for k, row in data["parity"].items():
         ok = row["param_diff"] <= 1e-6 and row["acc_diff"] <= 1e-6
@@ -128,6 +126,7 @@ def _run() -> None:
              f"sim_cloud={row['simulated_cloud_bits']:.3e} bits", **row)
     if bad:
         emit("distributed_mesh", 0.0, "FAILED: " + ", ".join(bad))
+        raise RuntimeError("distributed_mesh checks failed: " + ", ".join(bad))
 
 
 if __name__ == "__main__":

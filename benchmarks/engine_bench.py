@@ -322,11 +322,21 @@ def bench_streaming() -> None:
     point (``ru_maxrss`` is a process-lifetime high-water mark — see
     ``benchmarks/streaming_point.py``).  The acceptance shape: peak RSS flat
     in M (the engine holds O(cohort) data + ~8 bytes/client of int32
-    metadata) and clients/sec a function of cohort size, not M."""
+    metadata) and clients/sec a function of cohort size, not M.
+
+    Each child takes the device itself, so the parent must not have touched
+    JAX: run it as ``python -m benchmarks.engine_bench --streaming``."""
     import json as _json
     import subprocess
     import sys as _sys
 
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "bench_streaming starts one JAX process per point; this process "
+            "already holds a JAX backend (and with it any accelerator)"
+        )
     sizes = [100_000, 1_000_000]
     cohort, rounds = (64, 2) if QUICK else (256, 5)
     points = []

@@ -5,7 +5,8 @@ step and (b) the HFL local + sync steps, and compares cross-edge collective
 bytes per step: the amortized HFL schedule moves cross-edge bytes only every
 T-th step — the paper's 75-85% round reduction, structurally.
 
-Runs in a subprocess so the main process keeps one visible device.
+Runs in a CPU-only subprocess (``JAX_PLATFORMS=cpu``) so the main process
+keeps its own devices, including an accelerator it may hold.
 """
 from __future__ import annotations
 
@@ -25,7 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.launch.specs import param_shapes, train_batch_specs
 from repro.distributed.sharding import param_specs, opt_state_specs
-from repro.distributed.axes import sharding_hints
+from repro.distributed.axes import auto_mesh, sharding_hints
 from repro.distributed.hfl_mesh import (
     hfl_batch_spec, hfl_param_specs, make_hfl_train_step, init_hfl_state,
 )
@@ -48,7 +49,7 @@ def coll_of(lowered, devs_per_edge=None):
 
 out = {}
 # (a) plain data parallel on (data=8, model=2)
-mesh = jax.make_mesh((8, 2), ("data", "model"))
+mesh = auto_mesh((8, 2), ("data", "model"))
 psds = param_shapes(cfg)
 pspec = param_specs(cfg, psds, "tp", mesh)
 ospec = opt_state_specs(pspec, jax.eval_shape(opt.init, psds), psds)
@@ -64,7 +65,7 @@ with mesh, sharding_hints(mesh):
 out["dp"] = coll_of(low, devs_per_edge=4)  # data=8,model=2: 'edge block'=4 devs
 
 # (b) HFL on (edge=4, eu=2, model=2)
-mesh = jax.make_mesh((4, 2, 2), ("edge", "eu", "model"))
+mesh = auto_mesh((4, 2, 2), ("edge", "eu", "model"))
 pspec_e = hfl_param_specs(param_specs(cfg, psds, "tp", mesh), ("edge",))
 st_sds = jax.eval_shape(lambda ps: init_hfl_state(ps, opt, E), psds)
 opt_spec_e = (jax.tree.map(lambda s: s, pspec_e), jax.tree.map(lambda s: s, pspec_e))
@@ -85,18 +86,16 @@ print(json.dumps(out))
 
 def main() -> None:
     src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = dict(os.environ, PYTHONPATH=src)
+    # the child is a CPU accounting tool on virtual devices: it never
+    # contends with a parent that holds an accelerator
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
-    try:
-        res = subprocess.run([sys.executable, "-c", _CODE], env=env,
-                             capture_output=True, text=True, timeout=1500)
-        if res.returncode != 0:
-            emit("hfl_collectives", 0.0, "FAILED: " + res.stderr.strip().splitlines()[-1][:120])
-            return
-        data = json.loads(res.stdout.strip().splitlines()[-1])
-    except Exception as e:  # noqa: BLE001
-        emit("hfl_collectives", 0.0, f"FAILED: {e}")
-        return
+    res = subprocess.run([sys.executable, "-c", _CODE], env=env,
+                         capture_output=True, text=True, timeout=1500)
+    if res.returncode != 0:
+        emit("hfl_collectives", 0.0, "FAILED: " + res.stderr.strip().splitlines()[-1][:120])
+        raise RuntimeError(f"hfl_collectives child failed:\n{res.stderr[-3000:]}")
+    data = json.loads(res.stdout.strip().splitlines()[-1])
     tot = {k: sum(v2 for k2, v2 in v.items() if k2 != "_cross_edge") for k, v in data.items()}
     xe = {k: v.get("_cross_edge", 0.0) for k, v in data.items()}
     for k in tot:

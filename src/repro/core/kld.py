@@ -29,7 +29,9 @@ def edge_class_counts(lam: jnp.ndarray, class_counts: jnp.ndarray) -> jnp.ndarra
     class_counts: (M, K) per-EU class histogram c_k^i.
     returns: (N, K) matrix  sum_i lam_ij * c_k^i    (numerator of eq. 28).
     """
-    return jnp.einsum("ij,ik->jk", lam, class_counts)
+    # HIGHEST: at default precision a TPU rounds the counts to bf16 (8-bit
+    # mantissa), so counts in the thousands come back off by ~10
+    return jnp.einsum("ij,ik->jk", lam, class_counts, precision=jax.lax.Precision.HIGHEST)
 
 
 def edge_distributions(lam: jnp.ndarray, class_counts: jnp.ndarray) -> jnp.ndarray:

@@ -67,6 +67,15 @@ def edge_mesh(n_devices: Optional[int] = None, *, devices=None):
     return Mesh(np.asarray(devs[:k]), (EDGE_AXIS,))
 
 
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axis types.  ``constrain`` pins layouts
+    with ``with_sharding_constraint``, which ``Explicit`` axes (the
+    ``make_mesh`` default) refuse."""
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 @contextlib.contextmanager
 def sharding_hints(mesh=None, *, batch_axes=None, model_axis="model"):
     """Derive hints from a mesh: batch axes = all non-model axes."""

@@ -150,7 +150,10 @@ def _small_mean(updates: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
     (same normalization guard as ``hier_aggregate``)."""
     w = weights.astype(jnp.float32)
     w = w / jnp.maximum(jnp.sum(w), 1e-30)
-    return jnp.tensordot(w, updates.astype(jnp.float32), axes=1).astype(updates.dtype)
+    # HIGHEST keeps FedAvg float32 on the TPU, whose default rounds to bf16
+    return jnp.tensordot(
+        w, updates.astype(jnp.float32), axes=1, precision=jax.lax.Precision.HIGHEST
+    ).astype(updates.dtype)
 
 
 def flat_mean(
@@ -173,7 +176,9 @@ def flat_mean(
     if backend == "reference":
         w = jnp.asarray(weights, dtype=jnp.float32)
         w = w / jnp.sum(w)
-        out = jnp.tensordot(w, updates.astype(jnp.float32), axes=1)
+        out = jnp.tensordot(
+            w, updates.astype(jnp.float32), axes=1, precision=jax.lax.Precision.HIGHEST
+        )
         return out.astype(updates.dtype)
     raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
 

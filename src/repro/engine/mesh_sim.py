@@ -39,12 +39,12 @@ mesh path is a topology-correctness + comm-accounting tool, not a speedup.
 from __future__ import annotations
 
 import dataclasses
+import warnings
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.hfl import HFLSchedule
@@ -194,6 +194,12 @@ class MeshSyncEngine(BatchedSyncEngine):
             k = min(len(jax.devices()), n)
             while n % k:
                 k -= 1
+            if k < len(jax.devices()):
+                warnings.warn(
+                    f"MeshSyncEngine: {n} edges do not divide over "
+                    f"{len(jax.devices())} devices; running on {k}",
+                    stacklevel=2,
+                )
             self.mesh = edge_mesh(k)
         else:
             self.mesh = edge_mesh(int(mesh))
@@ -220,8 +226,8 @@ class MeshSyncEngine(BatchedSyncEngine):
 
         def smap(fn, n_in, out_specs):
             return jax.jit(
-                shard_map(fn, mesh=self.mesh, in_specs=(pe,) * n_in,
-                          out_specs=out_specs)
+                jax.shard_map(fn, mesh=self.mesh, in_specs=(pe,) * n_in,
+                              out_specs=out_specs)
             )
 
         def _starts(edge_mat, eo):
@@ -259,7 +265,8 @@ class MeshSyncEngine(BatchedSyncEngine):
             wf = w.astype(jnp.float32)
             wsum = jax.lax.psum(jnp.sum(wf), EDGE_AXIS)
             wn = wf / jnp.maximum(wsum, 1e-30)
-            part = jnp.tensordot(wn, edge_mat.astype(jnp.float32), axes=1)
+            part = jnp.tensordot(wn, edge_mat.astype(jnp.float32), axes=1,
+                                 precision=jax.lax.Precision.HIGHEST)
             return jax.lax.psum(part, EDGE_AXIS).astype(edge_mat.dtype)
 
         self._starts_fn = smap(_starts, 2, pe)
@@ -283,8 +290,8 @@ class MeshSyncEngine(BatchedSyncEngine):
                 return ravel_batched(params), loss
 
             fn = jax.jit(
-                shard_map(ep, mesh=self.mesh, in_specs=(pe, pe, pe),
-                          out_specs=(pe, pe))
+                jax.shard_map(ep, mesh=self.mesh, in_specs=(pe, pe, pe),
+                              out_specs=(pe, pe))
             )
             self._epoch_fns[key] = fn
         return fn
@@ -478,7 +485,7 @@ def mesh_segment_mean(
             return hier_segment_aggregate_ref(u, s - base, ww, epe)
 
         fn = jax.jit(
-            shard_map(_agg, mesh=mesh, in_specs=(pe, pe, pe), out_specs=pe)
+            jax.shard_map(_agg, mesh=mesh, in_specs=(pe, pe, pe), out_specs=pe)
         )
         _SEG_MEAN_CACHE[key] = fn
     ns = NamedSharding(mesh, P(EDGE_AXIS))

@@ -11,9 +11,12 @@ over the updates.
 The segment reduction is phrased as a one-hot contraction: a normalized
 ``(E, N)`` weight matrix ``W`` with ``W[e, i] = w_i / sum_{seg(k)=e} w_k``
 if ``seg(i) == e`` else 0 is built once (it is O(E*N) scalars), and each
-grid step multiplies it against the ``(N, block)`` VMEM slab of updates on
-the MXU — the update matrix is read from HBM exactly once regardless of E,
-and the output shape is static, so repeated rounds never re-compile.
+grid step multiplies one ``(E, row tile)`` block of it against the matching
+``(row tile, block)`` VMEM slab of updates on the MXU, accumulating into an
+f32 VMEM accumulator across the row tiles (``hier_aggregate.tiling``).  The
+update matrix is read from HBM exactly once regardless of E, the
+fast-memory footprint does not grow with N, and the output shape is
+static, so repeated rounds never re-compile.
 
 Rows whose segment is empty (or whose weights sum to ~0) come back as
 zeros; callers overlay prior state (the engines keep the previous edge
@@ -32,12 +35,26 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.hier_aggregate import COMPILER_PARAMS, pad_updates, round_up, tiling
 
 
-def _seg_kernel(w_ref, x_ref, o_ref):
-    w = w_ref[...].astype(jnp.float32)  # (E, N) normalized one-hot weights
-    x = x_ref[...].astype(jnp.float32)  # (N, block)
-    o_ref[...] = jnp.dot(w, x, preferred_element_type=jnp.float32).astype(o_ref.dtype)
+def _seg_kernel(w_ref, x_ref, o_ref, acc_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    w = w_ref[...]  # (E, row_tile) normalized one-hot weights, f32
+    x = x_ref[...].astype(jnp.float32)  # (row_tile, block)
+    # HIGHEST: at default precision the TPU's MXU rounds f32 operands to
+    # bf16, which puts ~1e-3 relative error on every edge model
+    acc_ref[...] += jnp.dot(w, x, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def _segment_weight_matrix(seg_ids: jnp.ndarray, weights: jnp.ndarray, n_segments: int):
@@ -74,20 +91,21 @@ def hier_segment_aggregate(
         return jnp.zeros((n_segments, d), updates.dtype)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    bn, np_, bd, dp = tiling(n, d, block)
+    ep = round_up(n_segments, 8)  # pad segments to the sublane tiling
     wmat = _segment_weight_matrix(jnp.asarray(seg_ids), jnp.asarray(weights), n_segments)
-    block = min(block, d)
-    pad = (-d) % block
-    x = jnp.pad(updates, ((0, 0), (0, pad))) if pad else updates
-    dp = d + pad
+    wmat = jnp.pad(wmat, ((0, ep - n_segments), (0, np_ - n)))
     out = pl.pallas_call(
         _seg_kernel,
-        grid=(dp // block,),
+        grid=(dp // bd, np_ // bn),
         in_specs=[
-            pl.BlockSpec((n_segments, n), lambda i: (0, 0)),
-            pl.BlockSpec((n, block), lambda i: (0, i)),
+            pl.BlockSpec((ep, bn), lambda i, k: (0, k)),
+            pl.BlockSpec((bn, bd), lambda i, k: (k, i)),
         ],
-        out_specs=pl.BlockSpec((n_segments, block), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n_segments, dp), updates.dtype),
+        out_specs=pl.BlockSpec((ep, bd), lambda i, k: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((ep, dp), updates.dtype),
+        scratch_shapes=[pltpu.VMEM((ep, bd), jnp.float32)],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(wmat, x)
-    return out[:, :d]
+    )(wmat, pad_updates(updates, np_, dp))
+    return out[:n_segments, :d]

@@ -11,26 +11,26 @@ over ``eu`` only; cloud aggregation reduces over (``pod``, ``edge``).
 """
 from __future__ import annotations
 
-import jax
+from repro.distributed.axes import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_hfl_mesh(*, multi_pod: bool = False, n_edges: int = 4):
     """(pod,) edge x eu x model factorization of the production mesh."""
     if multi_pod:
         assert 16 % n_edges == 0
-        return jax.make_mesh((2, n_edges, 16 // n_edges, 16), ("pod", "edge", "eu", "model"))
+        return auto_mesh((2, n_edges, 16 // n_edges, 16), ("pod", "edge", "eu", "model"))
     assert 16 % n_edges == 0
-    return jax.make_mesh((n_edges, 16 // n_edges, 16), ("edge", "eu", "model"))
+    return auto_mesh((n_edges, 16 // n_edges, 16), ("edge", "eu", "model"))
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *, multi_pod: bool = False):
     """Small mesh for CPU debugging (requires >= n_data*n_model host devices)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return auto_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))

@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config, list_archs
 from repro.serving.engine import Request, ServeEngine
 from repro.telemetry import Telemetry
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--telemetry", default=None, metavar="DIR",
                     help="write trace.json / metrics.json artifacts here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full_config else get_smoke_config(args.arch)
     tel = Telemetry(out_dir=args.telemetry)

@@ -24,6 +24,8 @@ from __future__ import annotations
 
 import argparse
 
+from repro.utils.compile_cache import enable_compile_cache
+
 # fault-injection presets for --faults (FaultSpec kwargs; "chaos" is the CI
 # chaos smoke: >=20% churn, lossy uplinks, finite batteries, fading drift)
 FAULT_PRESETS = {
@@ -207,6 +209,7 @@ def main() -> None:
                     help="record telemetry; write artifacts to DIR")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.paper or not args.arch:
         run_paper(args)
     else:

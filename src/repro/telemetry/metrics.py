@@ -130,18 +130,8 @@ def register_jit(name: str, fn: Callable) -> Callable:
 
 
 def jit_cache_sizes() -> Dict[str, int]:
-    """Compiled-program cache size per registered jit function.
-
-    A function absent from the result does not expose ``_cache_size`` under
-    the running jax version (the accounting degrades gracefully).
-    """
-    out: Dict[str, int] = {}
-    for name, fn in _JITS.items():
-        try:
-            out[name] = int(fn._cache_size())
-        except Exception:  # pragma: no cover - jax-version dependent
-            continue
-    return out
+    """Compiled-program cache size per registered jit function."""
+    return {name: int(fn._cache_size()) for name, fn in _JITS.items()}
 
 
 def registered_jits() -> Dict[str, Callable]:

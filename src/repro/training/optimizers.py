@@ -55,8 +55,10 @@ def adam(
     mdt = moment_dtype or jnp.float32
 
     def init(params):
-        m = jax.tree.map(lambda p: jnp.zeros(p.shape, mdt), params)
-        v = jax.tree.map(lambda p: jnp.zeros(p.shape, mdt), params)
+        # zeros_like keeps each leaf's varying-axis type, so the moments
+        # match the updated carry inside a shard_map'd scan
+        m = jax.tree.map(lambda p: jnp.zeros_like(p, dtype=mdt), params)
+        v = jax.tree.map(lambda p: jnp.zeros_like(p, dtype=mdt), params)
         return (m, v)
 
     def update(params, grads, state, step):

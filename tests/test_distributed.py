@@ -162,12 +162,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.launch.specs import param_shapes, train_batch_specs
 from repro.distributed.sharding import param_specs, opt_state_specs
-from repro.distributed.axes import sharding_hints
+from repro.distributed.axes import auto_mesh, sharding_hints
 from repro.models.config import InputShape
 from repro.training.train_step import make_train_step, TrainState
 from repro.training.optimizers import adam
 
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = auto_mesh((4, 4), ("data", "model"))
 ok = {}
 for arch in ["qwen3-14b", "dbrx-132b", "jamba-1.5-large-398b", "rwkv6-7b"]:
     cfg = dataclasses.replace(get_smoke_config(arch), remat=True)

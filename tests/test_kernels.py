@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.hier_aggregate import hier_aggregate
+from repro.kernels.hier_aggregate import ROW_TILE, hier_aggregate
 from repro.kernels.segment_aggregate import hier_segment_aggregate
 from repro.kernels.topk_gating import topk_gating
 from repro.kernels.ref import (
@@ -50,7 +50,7 @@ def test_flash_attention_sliding_window(window):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("n,d,block", [(4, 1000, 256), (13, 14789, 4096), (32, 512, 512)])
+@pytest.mark.parametrize("n,d,block", [(4, 1000, 256), (13, 14789, 4096), (32, 512, 512), (600, 300, 128)])
 def test_hier_aggregate_sweep(dtype, n, d, block):
     u = jax.random.normal(jax.random.PRNGKey(0), (n, d)).astype(dtype)
     w = jax.random.uniform(jax.random.PRNGKey(1), (n,), minval=0.05)
@@ -77,6 +77,8 @@ RAGGED_CASES = [
     (np.zeros(9, int), 1),
     # every client its own edge + one empty trailing edge
     (np.arange(9), 10),
+    # three VMEM row tiles, the last one part zero-weight padding; edge 6 empty
+    (np.random.default_rng(0).integers(0, 6, 2 * ROW_TILE + 37), 7),
 ]
 
 
