@@ -1,0 +1,12 @@
+"""The harness modules and the program, importable from these tests.
+
+    PYTHONPATH=src python -m pytest -q benchmarks/chip/tests
+"""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE.parent))
+sys.path.insert(0, str(HERE.parents[2] / "src"))
