@@ -1,0 +1,10 @@
+"""Host milliseconds per cloud round in the program's ``eval`` spans: the
+test-set evaluation that ends every round (upload of the test batches, the
+eager forward passes, one blocking read per batch)."""
+
+
+def read(run):
+    spans = run.spans_named("eval")
+    if not spans or not run.rounds:
+        return None
+    return sum(b - a for _, a, b, _ in spans) / run.rounds / 1e6

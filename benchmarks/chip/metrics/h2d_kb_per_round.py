@@ -1,0 +1,14 @@
+"""Host-to-device kilobytes (1,000 bytes) per cloud round that the round
+loop uploads: the ``h2d_bytes`` attributes of the window's spans (batch
+indices, starts and aggregation weights, the test batches), each upload
+counted once, on the innermost span open at it."""
+
+SPANS = ("cloud_round", "assignment", "cohort_epoch", "edge_aggregate", "cloud_reduce", "eval")
+
+
+def read(run):
+    counted = [s[3]["h2d_bytes"] for name in SPANS for s in run.spans_named(name)
+               if "h2d_bytes" in s[3]]
+    if not counted or not run.rounds:
+        return None
+    return sum(counted) / run.rounds / 1e3

@@ -365,9 +365,12 @@ class BatchedSyncEngine:
 
     def _cloud_mean(self, edge_mat: jnp.ndarray, weights) -> jnp.ndarray:
         """Cloud FedAvg of one group's (E, D) edge matrix (paper eq. 9).
-        Traceable (``tel.jit_cost`` lowers it); the mesh engine overrides
-        this with the two-stage partial-sum + ``psum`` reduction — the only
+        Host ``weights`` are uploaded through ``tel.upload``.  Traceable
+        (``tel.jit_cost`` lowers it); the mesh engine overrides this with
+        the two-stage partial-sum + ``psum`` reduction — the only
         cross-edge collective on the mesh."""
+        if isinstance(weights, np.ndarray):
+            weights = self.tel.upload(weights)
         return flat_mean(edge_mat, weights, backend=self.backend)
 
     # -- one edge round, device pipeline --------------------------------------
@@ -433,11 +436,11 @@ class BatchedSyncEngine:
         def starts_for(ids: np.ndarray, g: int) -> jnp.ndarray:
             if self._single_edge:
                 return jnp.take(
-                    edge_mats[g], jnp.asarray(self._client_edge[ids], jnp.int32), axis=0
+                    edge_mats[g], tel.upload(self._client_edge[ids], np.int32), axis=0
                 )
             if g not in starts_full:
                 starts_full[g] = self._client_starts(edge_mats[g])
-            return starts_full[g][jnp.asarray(ids, jnp.int32)]
+            return jnp.take(starts_full[g], tel.upload(ids, np.int32), axis=0)
         # train each cohort flat-major: starts gather -> per-epoch on-device
         # batch gather -> fused (C, D)-in/(C, D)-out epoch.  Losses stay on
         # device until metrics time so the aggregation dispatches below can
@@ -456,7 +459,9 @@ class BatchedSyncEngine:
             ) as sp:
                 flat = starts_for(g.members, gi)
                 for e in range(g.idx.shape[1]):
-                    xb, yb = self.store.gather(g.members, g.idx[:, e])
+                    xb, yb = self.store.gather(
+                        tel.upload(g.members, np.int32), tel.upload(g.idx[:, e], np.int32)
+                    )
                     if e == 0:
                         cost = tel.jit_cost(
                             "cohort_epoch_flat", _cohort_epoch_flat,
@@ -492,7 +497,9 @@ class BatchedSyncEngine:
             quantizing = not compressing and prog.quantizes_upload
             if compressing or quantizing:
                 start_rows = starts_for(job_cids, gi)
-                trained_rows = upd_matrix[jnp.asarray(row_of[job_cids], jnp.int32)]
+                trained_rows = jnp.take(
+                    upd_matrix, tel.upload(row_of[job_cids], np.int32), axis=0
+                )
                 if quantizing:
                     # program-level upload transform (FedSGD fp16 gradients):
                     # one batched op over the (C, D) matrices, no per-row state
@@ -531,12 +538,12 @@ class BatchedSyncEngine:
                 ):
                     upd = upd_matrix  # rows already in pair order: skip the gather
                 else:
-                    upd = upd_matrix[jnp.asarray(take, jnp.int32)]
+                    upd = jnp.take(upd_matrix, tel.upload(take, np.int32), axis=0)
                 # edges with no participants of this group keep their previous
                 # group model
                 has = np.bincount(pe_g, weights=part_pairs, minlength=n) > 0
-                w_dev = jnp.asarray(self._data_sizes[pc_g] * part_pairs)
-                has_dev = jnp.asarray(has)
+                w_dev = tel.upload(self._data_sizes[pc_g] * part_pairs)
+                has_dev = tel.upload(has)
                 cost = tel.jit_cost(
                     "segment_agg_keep", _segment_agg_keep,
                     upd, pe_g_dev, w_dev, has_dev, edge_mats[gi], n, self.backend,
@@ -678,6 +685,10 @@ class BatchedSyncEngine:
         return out
 
     def run(self, cloud_rounds: int, eval_every: int = 1) -> SimResult:
+        with self.tel.watch_gc():
+            return self._run(cloud_rounds, eval_every)
+
+    def _run(self, cloud_rounds: int, eval_every: int) -> SimResult:
         n = self.assignment.shape[1]
         n_groups = len(self.groups)
         history: List[RoundMetrics] = []
@@ -695,7 +706,8 @@ class BatchedSyncEngine:
             self._round = b
             acc = None
             losses: List = []
-            with self.tel.span("cloud_round", round=b, engine=engine_name):
+            gc0 = self.tel.gc_seconds
+            with self.tel.span("cloud_round", round=b, engine=engine_name) as rsp:
                 if self.faults is not None:
                     self._maybe_repair(b)
                     if self.faults.spec.reassign:
@@ -751,11 +763,12 @@ class BatchedSyncEngine:
                         global_rows = self._apply_server_momentum(
                             global_rows, new_rows
                         )
-                    losses = (
-                        list(np.concatenate([np.asarray(c) for c in losses]))
-                        if losses
-                        else []
-                    )
+                    with self.tel.span("fetch", what="losses"):
+                        losses = (
+                            list(np.concatenate([np.asarray(c) for c in losses]))
+                            if losses
+                            else []
+                        )
                 else:
                     edge_rows = [[row] * n for row in global_rows]
                     for k in range(self.schedule.edge_per_cloud):
@@ -810,12 +823,15 @@ class BatchedSyncEngine:
                                         self.packs[g].unravel(global_rows[g]),
                                         self.groups[g],
                                         self.test,
+                                        telemetry=self.tel,
                                     )
                                     for g in range(n_groups)
                                 ]
                             )
                         )
                         sp.set(acc=acc)
+                if self.tel.enabled:
+                    rsp.set(gc_s=self.tel.gc_seconds - gc0)
             round_wall = time.perf_counter() - t_round
             round_sim = (self.clock.seconds - sim0) if self.clock is not None else 0.0
             wall_accum += round_wall

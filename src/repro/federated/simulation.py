@@ -80,15 +80,21 @@ def central_reference_step(params, data: Dataset, rng, batch: int, program):
     return params
 
 
-def evaluate(params, program, test: Dataset, batch: int = 512) -> float:
+def evaluate(
+    params, program, test: Dataset, batch: int = 512, telemetry=NULL_TELEMETRY
+) -> float:
     """Weighted mean of ``program.metric`` over the test set (classification
-    accuracy for the CNN/MLP, next-token accuracy for the LM)."""
+    accuracy for the CNN/MLP, next-token accuracy for the LM).  With
+    ``telemetry`` on, the test batches' bytes count as ``h2d_bytes`` on the
+    open span and each blocking read is a ``fetch`` span (``what="eval"``)."""
     program = as_program(program)
     accs, ns = [], []
     for i in range(0, len(test), batch):
-        x = jnp.asarray(test.x[i : i + batch])
-        y = jnp.asarray(test.y[i : i + batch])
-        accs.append(float(program.metric(params, x, y)) * len(y))
+        x = telemetry.upload(test.x[i : i + batch])
+        y = telemetry.upload(test.y[i : i + batch])
+        metric = program.metric(params, x, y)
+        with telemetry.span("fetch", what="eval"):
+            accs.append(float(metric) * len(y))
         ns.append(len(y))
     return float(np.sum(accs) / np.sum(ns))
 

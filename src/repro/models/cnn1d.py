@@ -68,7 +68,8 @@ def _conv1d_same(x, w, b):
 
 def _maxpool2(x):
     l = x.shape[1] - (x.shape[1] % 2)
-    x = x[:, :l]
+    # a static lax slice: eager ``x[:, :l]`` uploads its bounds as scalars
+    x = jax.lax.slice_in_dim(x, 0, l, axis=1)
     return jnp.max(x.reshape(x.shape[0], l // 2, 2, x.shape[2]), axis=2)
 
 

@@ -6,6 +6,13 @@ One :class:`Telemetry` object follows a simulation run end-to-end:
   thread-safe) on every hot path of both engines.
 * ``tel.sim_span("upload", t0, t1, client=i, edge=j)`` — the async engine's
   schedule on a *simulated-time* track (``EventQueue.now`` seconds).
+* ``tel.upload(x, dtype)`` — an explicit host→device copy whose bytes land
+  in the ``h2d_bytes`` counter and on the innermost open span.
+* ``with tel.watch_gc():`` — one ``gc`` span per Python garbage collection
+  while the block runs (the sync engines wrap ``run()`` in it).
+* ``Telemetry(profile=True)`` — every wall span is also a
+  ``jax.profiler.TraceAnnotation`` carrying its ``sid``, so a running
+  profiler trace holds the spans on the device trace's clock.
 * ``tel.metrics`` — counters/gauges/histograms (cohort occupancy, padding
   waste, staleness distribution, eval accuracy, ...).
 * ``tel.jit_cost(key, fn, *args)`` — analytic FLOPs / bytes-moved for a
@@ -27,9 +34,14 @@ User-facing knob: ``Scenario.simulate(telemetry=...)`` accepts ``True``
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
 from pathlib import Path
 from typing import Dict, List, Optional
+
+import jax
+import numpy as np
 
 from repro.telemetry.metrics import (  # noqa: F401  (re-exports)
     MetricsRegistry,
@@ -63,23 +75,54 @@ class Telemetry:
 
     enabled = True
 
-    def __init__(self, out_dir=None) -> None:
-        self.tracer = Tracer()
+    def __init__(self, out_dir=None, profile: bool = False) -> None:
+        self.tracer = Tracer(profile=profile)
         self.metrics = MetricsRegistry()
         self.rounds: List[dict] = []
         self.out_dir: Optional[Path] = Path(out_dir) if out_dir else None
         self._cost_cache: Dict[tuple, dict] = {}
         self._span_mark = 0
+        self.gc_seconds = 0.0  # total of the gc spans recorded so far
+        self._gc_span = None
 
     # -- tracing -------------------------------------------------------
     def span(self, name: str, **attrs):
         return self.tracer.span(name, **attrs)
 
-    def instant(self, name: str, **attrs) -> None:
-        self.tracer.instant(name, **attrs)
-
     def sim_span(self, name: str, t0: float, t1: float, **attrs) -> None:
         self.tracer.sim_span(name, t0, t1, **attrs)
+
+    # -- host->device traffic -------------------------------------------
+    def upload(self, x, dtype=None):
+        """``x`` copied to the default device as ``dtype``, explicitly (so
+        ``jax.transfer_guard_host_to_device("disallow")`` lets it pass).
+        The device array's bytes add to the ``h2d_bytes`` counter and to
+        the ``h2d_bytes`` attribute of the innermost open span."""
+        arr = jax.device_put(np.asarray(x, dtype))
+        self.metrics.inc("h2d_bytes", arr.nbytes)
+        self.tracer.add_to_open("h2d_bytes", arr.nbytes)
+        return arr
+
+    # -- garbage-collector pauses --------------------------------------
+    @contextlib.contextmanager
+    def watch_gc(self):
+        """Record a ``gc`` span (attr ``generation``) per collection while
+        the block runs; ``gc_seconds`` accumulates their durations.  The
+        callback is removed on exit, also on error."""
+        gc.callbacks.append(self._on_gc)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # "start" and "stop" come in pairs, on the collecting thread
+        if phase == "start":
+            self._gc_span = self.tracer.span("gc", generation=info["generation"])
+            self._gc_span.__enter__()
+        else:
+            self._gc_span.__exit__(None, None, None)
+            self.gc_seconds += self._gc_span.duration
 
     # -- analytic cost -------------------------------------------------
     def jit_cost(self, key: str, fn, *args, **kwargs) -> Optional[dict]:
@@ -173,15 +216,19 @@ class _NullTelemetry:
     metrics = NULL_METRICS
     rounds: List[dict] = []
     out_dir = None
+    gc_seconds = 0.0
 
     def span(self, name: str, **attrs):
         return NULL_SPAN
 
-    def instant(self, name: str, **attrs) -> None:
-        pass
-
     def sim_span(self, name: str, t0: float, t1: float, **attrs) -> None:
         pass
+
+    def upload(self, x, dtype=None):
+        return jax.device_put(np.asarray(x, dtype))
+
+    def watch_gc(self):
+        return NULL_SPAN
 
     def jit_cost(self, key: str, fn, *args, **kwargs) -> None:
         return None

@@ -19,6 +19,11 @@ Exports:
   Wall spans live under pid 1, simulated-time spans under pid 2, so the two
   time bases never share an axis.
 
+Profiler link: ``Tracer(profile=True)`` also opens every wall span as a
+``jax.profiler.TraceAnnotation(name, sid=..., parent=...)`` while it is
+open, so a running ``jax.profiler`` trace carries the spans on the device
+trace's clock; its ``/host`` events join ``trace.jsonl`` rows by ``sid``.
+
 Timing caveat: wall spans measure *host-side* time around jax dispatch; they
 do not force ``block_until_ready`` (that would perturb the very pipeline
 being observed).  Spans that contain an eval or a numpy conversion are
@@ -85,7 +90,7 @@ class Span:
 class _SpanCtx:
     """Context manager for one in-flight wall span (one per ``span()`` call)."""
 
-    __slots__ = ("_tracer", "name", "attrs", "sid", "parent", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "sid", "parent", "_t0", "_t1", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -93,7 +98,13 @@ class _SpanCtx:
         self.attrs = attrs
         self.sid = -1
         self.parent: Optional[int] = None
-        self._t0 = 0.0
+        self._t0 = self._t1 = 0.0
+        self._ann = None
+
+    @property
+    def duration(self) -> float:
+        """Seconds the span was open (once closed)."""
+        return self._t1 - self._t0
 
     def set(self, **attrs) -> "_SpanCtx":
         """Attach attributes to the span while it is open."""
@@ -106,17 +117,27 @@ class _SpanCtx:
         self.sid = next(tr._ids)
         self.parent = stack[-1].sid if stack else None
         stack.append(self)
+        if tr.profile:
+            from jax.profiler import TraceAnnotation
+
+            ids = {"sid": self.sid}
+            if self.parent is not None:
+                ids["parent"] = self.parent
+            self._ann = TraceAnnotation(self.name, **ids)
+            self._ann.__enter__()
         self._t0 = tr.now()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
-        t1 = tr.now()
+        self._t1 = tr.now()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()
         tr._append(
-            Span(self.name, self._t0, t1, self.sid, self.parent,
+            Span(self.name, self._t0, self._t1, self.sid, self.parent,
                  threading.get_ident() & 0xFFFF, "wall", self.attrs)
         )
         return False
@@ -124,10 +145,15 @@ class _SpanCtx:
 
 class Tracer:
     """Thread-safe span recorder.  All public methods may be called from
-    any thread; per-thread nesting stacks give correct parent links."""
+    any thread; per-thread nesting stacks give correct parent links.
+    ``profile=True`` mirrors every wall span into the ``jax.profiler``
+    trace (module docstring)."""
 
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
+    def __init__(self, profile: bool = False) -> None:
+        self.profile = profile
+        # re-entrant: a GC callback (``Telemetry.watch_gc``) may close a
+        # span on a thread that already holds the lock
+        self._lock = threading.RLock()
         self._local = threading.local()
         self._ids = itertools.count()
         self._epoch = time.perf_counter()
@@ -153,11 +179,13 @@ class Tracer:
         """Open a wall-clock span: ``with tracer.span("eval", round=r):``."""
         return _SpanCtx(self, name, attrs)
 
-    def instant(self, name: str, **attrs) -> None:
-        """Record a zero-duration wall event."""
-        t = self.now()
-        self._append(Span(name, t, t, next(self._ids), None,
-                          threading.get_ident() & 0xFFFF, "wall", attrs))
+    def add_to_open(self, key: str, value) -> None:
+        """Add ``value`` to attribute ``key`` of this thread's innermost
+        open span (nothing when no span is open)."""
+        stack = self._stack()
+        if stack:
+            attrs = stack[-1].attrs
+            attrs[key] = attrs.get(key, 0) + value
 
     def sim_span(self, name: str, t0: float, t1: float, *, tid: int = 0,
                  **attrs) -> None:
@@ -241,9 +269,6 @@ class NullTracer:
 
     def span(self, name: str, **attrs) -> _NullSpan:
         return NULL_SPAN
-
-    def instant(self, name: str, **attrs) -> None:
-        pass
 
     def sim_span(self, name: str, t0: float, t1: float, **attrs) -> None:
         pass
