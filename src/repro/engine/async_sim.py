@@ -60,9 +60,9 @@ from repro.engine.store import DeviceShardStore
 from repro.federated.client import FLClient
 from repro.federated.programs import as_program, group_edge_sizes
 from repro.federated.simulation import (
+    Evaluator,
     RoundMetrics,
     SimResult,
-    evaluate,
     hetero_final_params,
 )
 from repro.telemetry import NULL_TELEMETRY, coerce_telemetry
@@ -220,6 +220,7 @@ class AsyncHFLEngine:
         # back to host batch stacking
         self.store = DeviceShardStore.build_if_economical(clients)
         self.tel = coerce_telemetry(telemetry) or NULL_TELEMETRY
+        self._evaluators = [Evaluator(p, test, telemetry=self.tel) for p in self.groups]
         self._round = 0
         if self.tel.enabled:
             counts = np.bincount(self.group_of, minlength=len(self.groups))
@@ -667,10 +668,8 @@ class AsyncHFLEngine:
                         acc = float(
                             np.mean(
                                 [
-                                    evaluate(
-                                        self.packs[g].unravel(global_rows[g]),
-                                        self.groups[g],
-                                        self.test,
+                                    self._evaluators[g](
+                                        self.packs[g].unravel(global_rows[g])
                                     )
                                     for g in range(n_groups)
                                 ]

@@ -45,7 +45,7 @@ from repro.engine.flatten import BACKENDS, FlatPack, flat_mean
 from repro.engine.store import PagedShardStore, _store_gather
 from repro.federated.programs import as_program
 from repro.federated.sampling import CohortSpec
-from repro.federated.simulation import RoundMetrics, SimResult, evaluate
+from repro.federated.simulation import Evaluator, RoundMetrics, SimResult
 from repro.telemetry import NULL_TELEMETRY, coerce_telemetry
 from repro.telemetry.report import CommDelta
 from repro.utils.tree import tree_size_bytes
@@ -165,6 +165,7 @@ class StreamSyncEngine:
         self.server_momentum = float(server_momentum)
         self._srv_vel = None
         self.tel = coerce_telemetry(telemetry) or NULL_TELEMETRY
+        self._evaluator = Evaluator(self.program, test, telemetry=self.tel)
         self._round = 0
 
     # -- one edge round over the sampled cohort ------------------------------
@@ -284,9 +285,7 @@ class StreamSyncEngine:
                 self.accountant.on_cloud_sync(n)
                 if b % eval_every == 0 or b == cloud_rounds:
                     with self.tel.span("eval", round=b) as sp:
-                        acc = evaluate(
-                            self.pack.unravel(global_row), self.program, self.test
-                        )
+                        acc = self._evaluator(self.pack.unravel(global_row))
                         sp.set(acc=acc)
             round_wall = time.perf_counter() - t_round
             wall_accum += round_wall

@@ -88,10 +88,10 @@ from repro.engine.store import DeviceShardStore
 from repro.federated.client import FLClient
 from repro.federated.programs import as_program, group_edge_sizes
 from repro.federated.simulation import (
+    Evaluator,
     RoundMetrics,
     SimResult,
     central_reference_step,
-    evaluate,
     hetero_final_params,
 )
 from repro.telemetry import NULL_TELEMETRY, coerce_telemetry, register_jit
@@ -206,6 +206,8 @@ class BatchedSyncEngine:
             clients, self.program, self.params, self.pack, seed, compression
         )
         self.groups, self.group_of = gs.programs, gs.group_of
+        # one evaluator per group: the test set stays on the device across runs
+        self._evaluators = [Evaluator(p, test, telemetry=self.tel) for p in self.groups]
         self.group_params, self.packs = gs.params, gs.packs
         self._group_bits, self._uplink_bits = gs.bits, gs.uplink_bits
         n_groups = len(self.groups)
@@ -819,11 +821,8 @@ class BatchedSyncEngine:
                         acc = float(
                             np.mean(
                                 [
-                                    evaluate(
-                                        self.packs[g].unravel(global_rows[g]),
-                                        self.groups[g],
-                                        self.test,
-                                        telemetry=self.tel,
+                                    self._evaluators[g](
+                                        self.packs[g].unravel(global_rows[g])
                                     )
                                     for g in range(n_groups)
                                 ]

@@ -8,6 +8,7 @@ material of paper Figs. 3-6.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -20,7 +21,7 @@ from repro.core.hfl import CommAccountant, HFLSchedule, WallClock, cloud_aggrega
 from repro.data.synthetic_health import Dataset
 from repro.federated.client import FLClient, _local_epoch
 from repro.federated.programs import as_program, group_clients, group_edge_sizes
-from repro.telemetry import NULL_TELEMETRY, coerce_telemetry
+from repro.telemetry import NULL_TELEMETRY, coerce_telemetry, register_jit
 from repro.telemetry.report import CommDelta
 from repro.utils.tree import tree_add, tree_size_bytes, tree_sub
 
@@ -80,23 +81,60 @@ def central_reference_step(params, data: Dataset, rng, batch: int, program):
     return params
 
 
+@functools.partial(jax.jit, static_argnames=("program", "batch"))
+def _eval_batches(params, x, y, program, batch: int):
+    """``(n_batches,)`` ``program.metric`` over the consecutive row slices
+    ``[i, i + batch)`` of the test set: the full batches through one
+    ``lax.map``, the remainder (if any) as one more call."""
+    n = x.shape[0]
+    full = n // batch
+    out = []
+    if full:
+        xs = x[: full * batch].reshape((full, batch) + x.shape[1:])
+        ys = y[: full * batch].reshape((full, batch) + y.shape[1:])
+        out.append(jax.lax.map(lambda xy: program.metric(params, *xy), (xs, ys)))
+    if n % batch:
+        out.append(program.metric(params, x[full * batch :], y[full * batch :])[None])
+    return jnp.concatenate(out)
+
+
+register_jit("eval_batches", _eval_batches)
+
+
+class Evaluator:
+    """Weighted mean of ``program.metric`` over a test set held on the
+    device (classification accuracy for the CNN/MLP, next-token accuracy
+    for the sequence programs).
+
+    The test set is uploaded once, here: its bytes count as ``h2d_bytes``
+    and one ``eval_test_uploads``.  Each call dispatches one compiled
+    program over the batches of ``batch`` rows and reads their metrics back
+    in one ``fetch`` span (``what="eval"``)."""
+
+    def __init__(self, program, test: Dataset, batch: int = 512, telemetry=NULL_TELEMETRY):
+        if not len(test):
+            raise ValueError("the test set is empty")
+        self.program = as_program(program)
+        self.batch = int(batch)
+        self.telemetry = telemetry
+        self.x = telemetry.upload(test.x)
+        self.y = telemetry.upload(test.y)
+        telemetry.metrics.inc("eval_test_uploads")
+        self.n = len(test)
+        self._sizes = np.minimum(self.batch, self.n - np.arange(0, self.n, self.batch))
+
+    def __call__(self, params) -> float:
+        metrics = _eval_batches(params, self.x, self.y, self.program, self.batch)
+        with self.telemetry.span("fetch", what="eval"):
+            metrics = np.asarray(metrics, np.float64)
+        return float(np.sum(metrics * self._sizes) / self.n)
+
+
 def evaluate(
     params, program, test: Dataset, batch: int = 512, telemetry=NULL_TELEMETRY
 ) -> float:
-    """Weighted mean of ``program.metric`` over the test set (classification
-    accuracy for the CNN/MLP, next-token accuracy for the LM).  With
-    ``telemetry`` on, the test batches' bytes count as ``h2d_bytes`` on the
-    open span and each blocking read is a ``fetch`` span (``what="eval"``)."""
-    program = as_program(program)
-    accs, ns = [], []
-    for i in range(0, len(test), batch):
-        x = telemetry.upload(test.x[i : i + batch])
-        y = telemetry.upload(test.y[i : i + batch])
-        metric = program.metric(params, x, y)
-        with telemetry.span("fetch", what="eval"):
-            accs.append(float(metric) * len(y))
-        ns.append(len(y))
-    return float(np.sum(accs) / np.sum(ns))
+    """One-off :class:`Evaluator` call (uploads ``test`` each time)."""
+    return Evaluator(program, test, batch, telemetry)(params)
 
 
 class HFLSimulation:
@@ -148,6 +186,7 @@ class HFLSimulation:
         self.server_momentum = float(server_momentum)
         self._srv_vel = None
         self.tel = coerce_telemetry(telemetry) or NULL_TELEMETRY
+        self._evaluator = Evaluator(self.program, test, telemetry=self.tel)
         self._round = 0
         # fault injection (repro.faults.FaultState); None = the historical
         # fault-free path, bit-identical to the golden trajectories
@@ -371,7 +410,7 @@ class HFLSimulation:
                     div = weight_divergence(global_params, self.central_params)
                 if b % eval_every == 0 or b == cloud_rounds:
                     with self.tel.span("eval", round=b) as sp:
-                        acc = evaluate(global_params, self.program, self.test)
+                        acc = self._evaluator(global_params)
                         sp.set(acc=acc)
             round_wall = time.perf_counter() - t_round
             round_sim = (
@@ -468,6 +507,7 @@ class HeteroHFLSimulation:
         self.tel = coerce_telemetry(telemetry) or NULL_TELEMETRY
         self._round = 0
         self.programs, self.group_of = group_clients(clients)
+        self._evaluators = [Evaluator(p, test, telemetry=self.tel) for p in self.programs]
         self.group_params = [
             p.init(jax.random.PRNGKey(seed)) for p in self.programs
         ]
@@ -590,7 +630,7 @@ class HeteroHFLSimulation:
                         acc = float(
                             np.mean(
                                 [
-                                    evaluate(group_params[g], self.programs[g], self.test)
+                                    self._evaluators[g](group_params[g])
                                     for g in range(n_groups)
                                 ]
                             )
@@ -643,6 +683,7 @@ def centralized_baseline(
         program.n_classes,
     )
     params = program.init(jax.random.PRNGKey(seed))
+    evaluator = Evaluator(program, test)
     history = []
     n = len(data)
     wall_accum = 0.0
@@ -653,7 +694,7 @@ def centralized_baseline(
         xb, yb = jnp.asarray(data.x[idx]), jnp.asarray(data.y[idx])
         params, loss = _local_epoch(params, xb, yb, program, steps, 1e-3)
         if r % eval_every == 0 or r == rounds:
-            acc = evaluate(params, program, test)
+            acc = evaluator(params)
             wall_accum += time.perf_counter() - t_round
             history.append(
                 RoundMetrics(r, acc, 0.0, float(loss), wall_seconds=wall_accum)

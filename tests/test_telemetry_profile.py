@@ -1,8 +1,9 @@
 """The round's tail in the program's telemetry: spans mirrored into a
 ``jax.profiler`` trace (``Telemetry(profile=True)``), the host-to-device
-byte count (``h2d_bytes``) against the shapes that are uploaded, the
-``fetch`` spans of the blocking reads, and the ``gc`` spans with the
-callback's lifetime tied to ``run()``."""
+byte count (``h2d_bytes``) against the shapes that are uploaded (the test
+set once, when the engine builds its evaluator), the ``fetch`` spans of the
+blocking reads, and the ``gc`` spans with the callback's lifetime tied to
+``run()``."""
 import gc
 import glob
 import os
@@ -80,25 +81,33 @@ def test_profile_mirrors_each_wall_span_once(tmp_path, scenario, assignment, pro
     assert {"cloud_round", "cohort_epoch", "eval", "fetch"} <= {e[0] for e in events}
 
 
-def _shape_bytes(spans, pairs: int, edges: int, edge_rounds: int, test) -> dict:
+def _shape_bytes(spans, pairs: int, edges: int, edge_rounds: int) -> dict:
     """Bytes the device round uploads, per span name, from the shapes: per
     cohort epoch span the (C,) int32 start-row ids and, per epoch, the (C,)
     cids and (C, steps, batch) int32 batch indices; per edge round the (P,)
     int32 pair gather, the (P,) float32 weights and the (E,) bool mask; per
-    cloud reduce the (E,) int32 edge sizes; the test set once per eval."""
+    cloud reduce the (E,) int32 edge sizes.  The eval uploads nothing: the
+    test set is on the device from the engine's construction on."""
     cohort = sum(4 * a["clients"] + a["epochs"] * 4 * a["clients"] * (1 + a["steps"] * a["batch"])
                  for a in (s.attrs for s in spans if s.name == "cohort_epoch"))
     return {
         "cohort_epoch": cohort,
         "edge_aggregate": edge_rounds * (4 * pairs + 4 * pairs + edges),
         "cloud_reduce": 4 * edges,
-        "eval": test.x.size * 4 + test.y.size * 4,
     }
+
+
+def _test_set_bytes(test) -> int:
+    """The float32 rows and int32 labels the evaluator uploads once."""
+    return test.x.size * 4 + test.y.size * 4
 
 
 def test_h2d_bytes_match_the_shapes_and_nothing_uploads_implicitly(scenario, assignment):
     tel = Telemetry()
     eng = _engine(scenario, assignment, tel)
+    built = tel.metrics.snapshot()["counters"]
+    assert built["h2d_bytes"] == _test_set_bytes(scenario.test) == 75_200
+    assert built["eval_test_uploads"] == 1
     eng.run(1)
     before = tel.metrics.snapshot()["counters"]["h2d_bytes"]
     with jax.transfer_guard_host_to_device("disallow"):
@@ -108,26 +117,30 @@ def test_h2d_bytes_match_the_shapes_and_nothing_uploads_implicitly(scenario, ass
         if "h2d_bytes" in s.attrs:
             got[s.name] = got.get(s.name, 0) + s.attrs["h2d_bytes"]
     m, n = assignment.shape
-    want = _shape_bytes(spans, int(np.count_nonzero(assignment)), n, EDGE_ROUNDS, scenario.test)
+    want = _shape_bytes(spans, int(np.count_nonzero(assignment)), n, EDGE_ROUNDS)
     assert (m, n) == (18, 5) and scenario.test.x.shape == (100, 187, 1)
     assert got == want
-    assert sum(want.values()) == 100_926
-    assert tel.metrics.snapshot()["counters"]["h2d_bytes"] - before == 100_926
+    assert sum(want.values()) == 25_726
+    after = tel.metrics.snapshot()["counters"]
+    assert after["h2d_bytes"] - before == 25_726
+    assert after["eval_test_uploads"] == 1
 
 
 def test_paper_round_h2d_bytes():
     """The paper's federation (Table 3: 18 EUs on 5 edges, four edge rounds,
     1,500 test samples of 187 x 1) on seed 3100000001 trains, each edge
     round, a cohort of 17 EUs at 128 steps and one of 1 EU at 16 (batch
-    10): the count that ``h2d_kb_per_round`` reads for that seed."""
+    10): the count that ``h2d_kb_per_round`` reads for that seed.  The
+    test set's 1,128,000 B count once, when the engine is built, outside
+    every round."""
     cohorts = [types.SimpleNamespace(name="cohort_epoch", attrs={
         "clients": c, "epochs": 1, "steps": st, "batch": 10})
         for _ in range(4) for c, st in ((17, 128), (1, 16))]
     test = types.SimpleNamespace(x=np.zeros((1_500, 187, 1)), y=np.zeros(1_500))
-    want = _shape_bytes(cohorts, 18, 5, 4, test)
-    assert want == {"cohort_epoch": 351_296, "edge_aggregate": 596,
-                    "cloud_reduce": 20, "eval": 1_128_000}
-    assert sum(want.values()) == 1_479_912
+    want = _shape_bytes(cohorts, 18, 5, 4)
+    assert want == {"cohort_epoch": 351_296, "edge_aggregate": 596, "cloud_reduce": 20}
+    assert sum(want.values()) == 351_912 == 1_479_912 - 1_128_000
+    assert _test_set_bytes(test) == 1_128_000
 
 
 def test_fetch_spans_are_children_of_eval_and_cloud_round(scenario, assignment):
@@ -140,8 +153,8 @@ def test_fetch_spans_are_children_of_eval_and_cloud_round(scenario, assignment):
     for s in fetch:
         want = "eval" if s.attrs["what"] == "eval" else "cloud_round"
         assert by_sid[s.parent].name == want
-    n_batches = -(-len(scenario.test) // 512)
-    assert sum(s.attrs["what"] == "eval" for s in fetch) == n_batches
+    # one read of the per-batch metrics, however many batches the test set has
+    assert sum(s.attrs["what"] == "eval" for s in fetch) == 1
 
 
 def test_gc_spans_and_round_total(scenario, assignment):

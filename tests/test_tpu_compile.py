@@ -58,3 +58,22 @@ def test_hier_aggregate_compiles_for_v5e(one_chip, n):
         ((n, D), jnp.float32), ((n,), jnp.float32),
     )
     assert "tpu_custom_call" in text
+
+
+def test_eval_program_compiles_for_v5e(one_chip):
+    """The test-set evaluation at the paper's size (1,500 rows of 187 x 1,
+    batches of 512): one program, its full batches in one loop."""
+    from repro.federated.programs import CNNProgram
+    from repro.federated.simulation import _eval_batches
+    from repro.models.cnn1d import HEARTBEAT_CNN
+
+    program = CNNProgram(HEARTBEAT_CNN)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(program.init, jax.random.PRNGKey(0)),
+    )
+    x = jax.ShapeDtypeStruct((1_500, 187, 1), jnp.float32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((1_500,), jnp.int32, sharding=one_chip)
+    compiled = _eval_batches.lower(params, x, y, program, 512).compile()
+    assert compiled.out_info.shape == (3,)
+    assert "while" in compiled.as_text()
