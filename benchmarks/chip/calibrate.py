@@ -52,7 +52,7 @@ def main(argv=None) -> int:
     for i, seed in enumerate(args.seeds):
         t0 = time.perf_counter()
         driver = bench.load_driver(cell["traffic"]["driver"])(cell["config"], cell["traffic"], seed)
-        prog, _ = bench.warm_up(driver.engine, counter)
+        prog, _ = bench.warm_up(driver, counter)
         fed = driver.federation()
         if args.emulate:
             fed = dataclasses.replace(fed, precision=reference.EMULATED_DEFAULT)

@@ -3,9 +3,10 @@
 A driver (``drivers/<name>.py``, named by a traffic file's ``"driver"``)
 builds the system under test through the program's own entry points
 (scenario builder, assignment, engine constructor), exposes the engine whose
-``run`` the timed window calls, and describes the same federation to the
-plain reference from the benchmark's own generators (``gen``), never from
-what the program made.
+``run`` the timed window calls, states in ``resumes`` whether that ``run``
+starts from the previous call's end model (else from the initial model),
+and describes the same federation to the plain reference from the
+benchmark's own generators (``gen``), never from what the program made.
 """
 from __future__ import annotations
 
@@ -52,6 +53,8 @@ class Driver:
     """The paper's federation: ``build_scenario`` (materialised shards), the
     assignment it names, and ``BatchedSyncEngine``'s device pipeline."""
 
+    resumes = False  # BatchedSyncEngine.run restarts from the initial model
+
     def __init__(self, cfg: dict, traffic: dict, seed: int, telemetry=None):
         from repro.engine import BatchedSyncEngine
         from repro.federated import build_scenario
@@ -77,4 +80,4 @@ class Driver:
             sizes=np.array([len(s) for s in shards]), edge_of=self.lam.argmax(axis=1),
             n_edges=n, edge_rounds=t["edge_rounds"], epochs=t["local_epochs"],
             batch=t["batch"], max_steps=t["max_steps"],
-            precision=self.cfg["train_matmul_precision"])
+            precision=self.cfg["train_matmul_precision"], resumes=self.resumes)

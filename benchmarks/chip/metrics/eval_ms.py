@@ -1,6 +1,7 @@
 """Host milliseconds per cloud round in the program's ``eval`` spans: the
-test-set evaluation that ends every round (upload of the test batches, the
-eager forward passes, one blocking read per batch)."""
+test-set evaluation that ends every round (the dispatch of one compiled
+program over the test set held on the device, and the one blocking read of
+its metrics)."""
 
 
 def read(run):

@@ -1,7 +1,8 @@
 """Host-to-device kilobytes (1,000 bytes) per cloud round that the round
 loop uploads: the ``h2d_bytes`` attributes of the window's spans (batch
-indices, starts and aggregation weights, the test batches), each upload
-counted once, on the innermost span open at it."""
+indices, starts and aggregation weights), each upload counted once, on the
+innermost span open at it.  The ``eval`` span adds none: the test set is
+uploaded once, when the engine is built, before the window."""
 
 SPANS = ("cloud_round", "assignment", "cohort_epoch", "edge_aggregate", "cloud_reduce", "eval")
 

@@ -9,6 +9,27 @@ size (paper eqs. 4-9).  Batch indices come from the same numpy stream the
 engines consume, drawn in the same order, so the reference follows the
 program's trajectory up to rounding.
 
+Who trains in an edge round (``Federation.members``):
+
+* unset (full participation, as ``BatchedSyncEngine`` runs it): the round
+  first draws one participation uniform per client, then every client with
+  data draws its batch indices in client order, and every client trains;
+* set: ``members(b, er)`` gives the sorted ids that train in edge round
+  ``er`` of cloud round ``b`` (both counted from 1 within each ``run``
+  call, as ``StreamSyncEngine`` keys its cohorts).  No participation
+  uniform is drawn; each member with data draws its batch indices in
+  ascending id order, as ``StreamCohortPlan.draw`` consumes the stream, and
+  only members train.  The round's work and host loops are O(cohort), never
+  O(clients).
+
+Either way each edge averages the clients that trained under it, weighted
+by data size; an edge with no such client keeps its model, and one whose
+clients all hold no data gets a zero model.  An empty client's loss counts
+as 0 in the round's mean.  The cloud weights each edge by the data of all
+clients attached to it, members or not.  ``Federation.resumes`` says
+whether each ``run`` call starts from the previous call's end model (the
+engine resumes) or from the initial model (the engine restarts).
+
 ``dtype=jnp.bfloat16`` runs the same arithmetic in bfloat16 (parameters,
 data, Adam moments, at the chip's default matmul precision): the precision
 below the configuration's float32, the control that has to come out not
@@ -27,7 +48,8 @@ import numpy as np
 
 # Faults planted in the reference put in the program's place:
 #   half_batch   -- every local step takes the mean loss over half its batch
-#   half_clients -- each edge averages only every other participating client
+#   half_clients -- every other client of an edge round's list (by position)
+#                   is left out of its edge's average
 FAULTS = ("half_batch", "half_clients")
 
 BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)
@@ -178,12 +200,11 @@ class Federation:
     """What one run of the reference needs, made by the benchmark itself.
 
     ``shard(cid)`` returns a client's (x, y); ``sizes`` and ``edge_of``
-    cover every client, and every client takes part in every edge round.
-    As the engine does, each edge round first draws one uniform per client
-    (its participation draw), then every client's batch indices.
-    ``precision`` is the matmul precision of local training that the
-    configuration states (``jax.default_matmul_precision``); FedAvg is
-    elementwise float32."""
+    cover every client (``edge_of`` -1: attached to no edge).  ``members``
+    and ``resumes`` are as the module's docstring says; the driver that
+    builds the engine sets both.  ``precision`` is the matmul precision of
+    local training that the configuration states
+    (``jax.default_matmul_precision``); FedAvg is elementwise float32."""
 
     cfg: CNN
     shard: Callable[[int], tuple]
@@ -196,10 +217,13 @@ class Federation:
     max_steps: int
     precision: str
     cloud_weights: np.ndarray = None
+    members: Optional[Callable[[int, int], np.ndarray]] = None
+    resumes: bool = False
 
     def __post_init__(self):
         if self.cloud_weights is None:
-            w = np.bincount(self.edge_of, weights=self.sizes.astype(np.float64),
+            att = self.edge_of >= 0
+            w = np.bincount(self.edge_of[att], weights=self.sizes[att].astype(np.float64),
                             minlength=self.n_edges)
             self.cloud_weights = np.maximum(w, 1.0)
 
@@ -207,8 +231,8 @@ class Federation:
 def run_calls(fed: Federation, seed: int, calls: Sequence[int], dtype=jnp.float32,
               fault: Optional[str] = None) -> List[dict]:
     """Follow the engine through ``calls`` successive ``run(r)`` calls, each
-    from the initial model (the engine's ``run`` restarts there) while the
-    batch-index stream runs on.
+    from the initial model or, where ``fed.resumes``, from the previous
+    call's end, while the batch-index stream runs on.
 
     Returns one dict per call: ``start`` and ``end`` global models (trees
     of numpy float64) and the mean local loss of each cloud round."""
@@ -219,15 +243,19 @@ def run_calls(fed: Federation, seed: int, calls: Sequence[int], dtype=jnp.float3
     out = []
     emulate = fed.precision == EMULATED_DEFAULT
     precision = "highest" if emulate else fed.precision
+    glob = init
     with jax.default_matmul_precision(precision if dtype == jnp.float32 else "default"):
         for r in calls:
-            glob = start = init
+            if not fed.resumes:
+                glob = init
+            start = glob
             losses = []
-            for _ in range(r):
+            for b in range(1, r + 1):
                 edges = [glob] * fed.n_edges
                 round_losses = []
-                for _ in range(fed.edge_rounds):
-                    edges, ls = _edge_round(fed, rng, edges, dtype, fault, emulate)
+                for er in range(1, fed.edge_rounds + 1):
+                    edges, ls = _edge_round(fed, rng, edges, dtype, fault, emulate,
+                                            _members(fed, rng, b, er))
                     round_losses += ls
                 glob = _cloud(fed, edges)
                 losses.append(float(np.mean(round_losses)))
@@ -235,9 +263,20 @@ def run_calls(fed: Federation, seed: int, calls: Sequence[int], dtype=jnp.float3
     return out
 
 
-def _edge_round(fed: Federation, rng, edges, dtype, fault, emulate):
-    rng.random(len(fed.sizes))
-    members = np.arange(len(fed.sizes))
+def _members(fed: Federation, rng, b: int, er: int) -> np.ndarray:
+    """Edge round ``er`` of cloud round ``b``'s clients that train, sorted."""
+    if fed.members is None:
+        rng.random(len(fed.sizes))  # the engine's participation draw
+        return np.arange(len(fed.sizes))
+    members = np.asarray(fed.members(b, er), np.int64)
+    if members.ndim != 1 or (np.diff(members) <= 0).any():
+        raise ValueError(f"members({b}, {er}) are not sorted distinct ids")
+    if len(members) and (np.asarray(fed.edge_of[members]) < 0).any():
+        raise ValueError(f"members({b}, {er}) name a client attached to no edge")
+    return members
+
+
+def _edge_round(fed: Federation, rng, edges, dtype, fault, emulate, members):
     idx = {}
     for cid in members:  # the draws run in client order before any training
         n = int(fed.sizes[cid])

@@ -129,9 +129,9 @@ class CompileCounter:
 
 
 def call(engine, rounds: int, start) -> dict:
-    """One ``engine.run(rounds)``, which starts from the initial model
-    ``start``.  Returns the start and end models as numpy trees, the
-    per-round mean local losses and the engine's result."""
+    """One ``engine.run(rounds)``, which starts from the model ``start``.
+    Returns the start and end models as numpy trees, the per-round mean
+    local losses and the engine's result."""
     import jax
     import numpy as np
 
@@ -141,20 +141,28 @@ def call(engine, rounds: int, start) -> dict:
             "losses": [m.mean_local_loss for m in res.history], "result": res}
 
 
-def warm_up(engine, counter: CompileCounter) -> tuple:
-    """The compared calls (``compare.CALLS``), then ``run(2)`` until a call
-    compiles nothing.  Returns (compared calls, seconds per warm round)."""
+def warm_up(driver, counter: CompileCounter) -> tuple:
+    """The compared calls (``compare.CALLS``) of ``driver.engine``, then
+    ``run(2)`` until a call compiles nothing.  Each compared call starts
+    from the initial model, or from the previous call's end where the
+    driver says that its engine's ``run`` resumes (``driver.resumes``).
+    Returns (compared calls, seconds per warm round)."""
     import statistics
 
     import compare
     import jax
     import numpy as np
 
-    init = jax.tree.map(np.asarray, engine.params)  # run() sets it to its end model
-    calls = [call(engine, r, init) for r in compare.CALLS]
+    engine = driver.engine
+    start = jax.tree.map(np.asarray, engine.params)  # run() sets it to its end model
+    calls = []
+    for r in compare.CALLS:
+        calls.append(call(engine, r, start))
+        if driver.resumes:
+            start = calls[-1]["end"]
     for _ in range(WARM_CALLS_MAX):
         before = counter.total
-        last = call(engine, 2, init)
+        last = call(engine, 2, start)
         if counter.total == before:
             break
     else:
@@ -244,7 +252,7 @@ def measure(cell: dict, devices, seed: int, seconds: float, trace: bool) -> dict
     driver = load_driver(cell["traffic"]["driver"])(cell["config"], cell["traffic"], seed,
                                                     telemetry=tel)
     engine = driver.engine
-    calls, per_round = warm_up(engine, counter)
+    calls, per_round = warm_up(driver, counter)
     window = min(seconds, TRACE_SECONDS) if trace else seconds
     rounds = max(2, int(math.ceil(window / per_round)))
 

@@ -1,4 +1,5 @@
-"""The harness modules and the program, importable from these tests.
+"""The harness modules, the program and the tests' own helpers, importable
+from these tests.
 
     PYTHONPATH=src python -m pytest -q benchmarks/chip/tests
 """
@@ -8,5 +9,6 @@ from pathlib import Path
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parent))
 sys.path.insert(0, str(HERE.parents[2] / "src"))

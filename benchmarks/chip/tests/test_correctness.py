@@ -3,9 +3,11 @@ the bfloat16 control and a broken timed path do not.
 
 Each test drives the harness's own ``measure()`` (the look for a chip
 skipped, the traffic cut down) or its reference, against the limits in
-``limits/<cell>.json``.
+``limits/<cell>.json``.  A cell's CPU cut, the traffic keys it overrides,
+is ``cuts/<cell>.json``.
 """
 import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -14,39 +16,24 @@ import pytest
 import compare
 import reference
 import run as bench
+from engine_faults import frozen_edges, half_batch
 
 SEED = 2**31 + 11
-TINY = {"heartbeat-paper": {"scale": 0.05, "test_per_class": 20}}
+CUTS = Path(__file__).resolve().parent / "cuts"
 CELLS = [w["name"] for w in json.loads((bench.ROOT / "BENCHMARK.json").read_text())["workloads"]]
 
 
 def _tiny_cell(workload):
+    cut = CUTS / f"{workload}.json"
+    if not cut.exists():
+        pytest.fail(f"cell {workload!r} has no CPU cut: add {cut}")
     cell = bench.load_cell(workload)
-    cell["traffic"] = dict(cell["traffic"], **TINY[workload])
+    cell["traffic"] = dict(cell["traffic"], **json.loads(cut.read_text()))
     return cell
 
 
 def _run(workload):
     return bench.measure(_tiny_cell(workload), jax.devices()[:1], SEED, 1.0, False)
-
-
-def _frozen_edges(monkeypatch):
-    """Every edge round returns the edge models it was given."""
-    monkeypatch.setattr("repro.engine.sync_sim._segment_agg_keep",
-                        lambda upd, seg, w, has, prev, n, backend: prev)
-
-
-def _half_batch(monkeypatch):
-    """Every local step takes its mean loss over half of its batch."""
-    import repro.engine.sync_sim as sync_sim
-
-    orig = sync_sim._cohort_epoch_flat
-
-    def half(flat, xb, yb, *args):
-        h = xb.shape[2] // 2
-        return orig(flat, xb[:, :, :h], yb[:, :, :h], *args)
-
-    monkeypatch.setattr(sync_sim, "_cohort_epoch_flat", half)
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -56,7 +43,7 @@ def test_sound_run_is_correct(workload):
     assert list(line)[-1] == "checks"
 
 
-@pytest.mark.parametrize("fault", [_frozen_edges, _half_batch], ids=["frozen", "half_batch"])
+@pytest.mark.parametrize("fault", [frozen_edges, half_batch], ids=["frozen", "half_batch"])
 @pytest.mark.parametrize("workload", CELLS)
 def test_broken_timed_path_is_not_correct(monkeypatch, workload, fault):
     fault(monkeypatch)
