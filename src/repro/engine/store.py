@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.telemetry import register_jit
+from repro.telemetry import NULL_TELEMETRY, register_jit
 
 # past this pad blow-up the store costs more memory than it saves time;
 # callers that can fall back to host batch stacking (async engine) do so
@@ -156,9 +156,16 @@ class PagedShardStore:
     than the slab cannot be resident at once and raises).  Hit/miss/eviction
     counters expose paging behaviour to benchmarks and tests.  Client ids
     within one ``ensure`` call must be unique (cohorts are).
+
+    With an enabled ``telemetry`` each :meth:`ensure` is a ``page_in`` span
+    (attrs ``clients``, ``misses``, ``hits``; its uploads go through
+    ``telemetry.upload``, so its ``h2d_bytes`` are the bytes paged in) and
+    adds ``page_in_s``, ``page_misses``, ``page_hits`` and
+    ``page_in_bytes`` to the span open around the call.
     """
 
-    def __init__(self, source, capacity: int, n_max: "int | None" = None):
+    def __init__(self, source, capacity: int, n_max: "int | None" = None,
+                 telemetry=NULL_TELEMETRY):
         sizes = np.asarray(source.sizes)
         if len(sizes) == 0:
             raise ValueError("PagedShardStore needs a non-empty source")
@@ -179,6 +186,7 @@ class PagedShardStore:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        self.tel = telemetry
 
     @classmethod
     def from_shards(cls, shards: Sequence, capacity: int):
@@ -211,6 +219,19 @@ class PagedShardStore:
             raise ValueError(
                 f"cohort of {len(cids)} exceeds paged-store capacity {self.capacity}"
             )
+        tel = self.tel
+        with tel.span("page_in", clients=len(cids)) as sp:
+            slots, misses = self._page_in(cids)
+            sp.set(misses=misses, hits=len(cids) - misses)
+        if tel.enabled:
+            for key, value in (("page_in_s", sp.duration), ("page_misses", misses),
+                               ("page_hits", len(cids) - misses),
+                               ("page_in_bytes", sp.attrs.get("h2d_bytes", 0))):
+                tel.tracer.add_to_open(key, value)
+        return slots
+
+    def _page_in(self, cids: np.ndarray) -> Tuple[np.ndarray, int]:
+        """(slots, misses) of :meth:`ensure`."""
         slots = np.empty(len(cids), np.int64)
         missing: List[int] = []
         for p, c in enumerate(cids.tolist()):
@@ -251,10 +272,10 @@ class PagedShardStore:
                 ms = np.concatenate([ms, np.repeat(ms[:1], pad)])
                 bx = np.concatenate([bx, np.repeat(bx[:1], pad, axis=0)])
                 by = np.concatenate([by, np.repeat(by[:1], pad, axis=0)])
-            sl = jnp.asarray(ms, jnp.int32)
-            self.x = self.x.at[sl].set(jnp.asarray(bx))
-            self.y = self.y.at[sl].set(jnp.asarray(by))
-        return slots
+            sl = self.tel.upload(ms, np.int32)
+            self.x = self.x.at[sl].set(self.tel.upload(bx))
+            self.y = self.y.at[sl].set(self.tel.upload(by))
+        return slots, len(missing)
 
     def gather(self, cids, idx) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """cids: (C,) client ids; idx: (C, steps, batch) in-shard indices."""

@@ -46,7 +46,7 @@ from repro.engine.store import PagedShardStore, _store_gather
 from repro.federated.programs import as_program
 from repro.federated.sampling import CohortSpec
 from repro.federated.simulation import Evaluator, RoundMetrics, SimResult
-from repro.telemetry import NULL_TELEMETRY, coerce_telemetry
+from repro.telemetry import NULL_TELEMETRY, coerce_telemetry, register_jit
 from repro.telemetry.report import CommDelta
 from repro.utils.tree import tree_size_bytes
 from functools import partial
@@ -75,6 +75,10 @@ def _edge_agg_finish(num, den, has, prev):
     and edges with no sampled member keep their previous model (``has``)."""
     mean = jnp.where(den[:, None] > 0, num / jnp.maximum(den, 1e-30)[:, None], 0.0)
     return jnp.where(has[:, None], mean, prev)
+
+
+register_jit("segment_sums", _segment_sums)
+register_jit("edge_agg_finish", _edge_agg_finish)
 
 
 class StreamSyncEngine:
@@ -158,13 +162,15 @@ class StreamSyncEngine:
         # working set: 2x the cohort so consecutive rounds' overlap pages
         # nothing, still O(cohort) device memory
         capacity = page_slots if page_slots is not None else 2 * cohort.size
-        self.store = PagedShardStore(source, capacity=max(capacity, cohort.size))
+        self.tel = coerce_telemetry(telemetry) or NULL_TELEMETRY
+        self.store = PagedShardStore(
+            source, capacity=max(capacity, cohort.size), telemetry=self.tel
+        )
         model_bits = tree_size_bytes(self.params) * 8
         self.accountant = CommAccountant(model_bits=model_bits)
         self._uplink_bits = self.program.uplink_bits(model_bits)
         self.server_momentum = float(server_momentum)
         self._srv_vel = None
-        self.tel = coerce_telemetry(telemetry) or NULL_TELEMETRY
         self._evaluator = Evaluator(self.program, test, telemetry=self.tel)
         self._round = 0
 
@@ -209,26 +215,26 @@ class StreamSyncEngine:
                     idx = np.concatenate(
                         [idx, np.zeros((pad,) + idx.shape[1:], idx.dtype)]
                     )
-                start = jnp.take(edge_mat, jnp.asarray(eo, jnp.int32), axis=0)
+                eo_j = tel.upload(eo, np.int32)
+                start = jnp.take(edge_mat, eo_j, axis=0)
                 flat = start
-                slots_j = jnp.asarray(slots, jnp.int32)
+                slots_j = tel.upload(slots, np.int32)
                 for e in range(idx.shape[1]):
                     xb, yb = _store_gather(
                         self.store.x, self.store.y, slots_j,
-                        jnp.asarray(idx[:, e], jnp.int32),
+                        tel.upload(idx[:, e], np.int32),
                     )
                     flat, loss = _cohort_epoch_flat(
                         flat, xb, yb, self.pack.spec, self.program, g.steps, g.lr
                     )
                 if self.program.quantizes_upload:
                     flat = self.program.quantize_upload(start, flat)
-                gnum, gden = _segment_sums(
-                    flat, jnp.asarray(eo, jnp.int32), jnp.asarray(w), self.n_edges
-                )
+                gnum, gden = _segment_sums(flat, eo_j, tel.upload(w), self.n_edges)
                 num = num + gnum
                 den = den + gden
             ids.append(g.members)
-            losses.append(np.asarray(loss)[:c])
+            with tel.span("fetch", what="losses"):
+                losses.append(np.asarray(loss)[:c])
         if len(passthrough):
             # empty shards participate with weight zero: they never move an
             # edge model, but they count for `has` and for accounting, same
@@ -243,7 +249,7 @@ class StreamSyncEngine:
             # sampled-member FedAvg: weights renormalize over the cohort,
             # edges with no sampled member keep their previous model
             has = np.bincount(seg, minlength=self.n_edges) > 0
-            edge_mat = _edge_agg_finish(num, den, jnp.asarray(has), edge_mat)
+            edge_mat = _edge_agg_finish(num, den, tel.upload(has), edge_mat)
         # compact cohort-only accounting with true client ids
         lam = np.zeros((len(cids), self.n_edges), np.int8)
         lam[np.arange(len(cids)), seg] = 1

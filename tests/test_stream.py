@@ -26,6 +26,7 @@ from repro.engine import (
 from repro.federated import CohortSpec, HFLSimulation, build_scenario, pareto_weights
 from repro.federated.sampling import _floyd_sample
 from repro.federated.stream import edge_kld_uniform, striped_assignment
+from repro.telemetry import Telemetry
 
 M, N_EDGES = 120, 4
 SCHEDULE = HFLSchedule(1, 1)
@@ -222,27 +223,40 @@ def test_sampling_layer_leaves_full_runs_bit_identical():
 
 
 # -- engine parity on sampled rounds --------------------------------------
-def test_stream_matches_sync_engine_on_cohort_rounds(
-    stream_sc, spec, stream_result, materialized
-):
+@pytest.fixture(scope="module", params=["heartbeat", "seizure"])
+def cohort_population(request, stream_sc, spec, stream_result):
+    """(lazy scenario, its 3-round stream run) for each health dataset: the
+    paper's heartbeat CNN (1 x 187 in) and its seizure CNN (19 x 178 in)."""
+    if request.param == "heartbeat":
+        return stream_sc, stream_result
+    sc = build_scenario(
+        request.param, lazy=True, n_eus=M, n_edges=N_EDGES, seed=3,
+        n_test_per_class=20,
+    )
+    return sc, sc.simulate(spec, cloud_rounds=3, schedule=SCHEDULE, seed=0)
+
+
+def test_stream_matches_sync_engine_on_cohort_rounds(cohort_population, spec):
     """The streaming engine (lazy source + paged store + O(cohort) partial
-    segment sums) tracks the materialized sync engine on the same cohort
-    draws: accuracies equal, parameters allclose (the partial-sum
-    association order differs, so bit-identity is not expected)."""
-    clients, lam = materialized
-    eng = BatchedSyncEngine(
-        clients, lam, stream_sc.program, stream_sc.test,
-        schedule=SCHEDULE, seed=0, cohort=spec,
-    )
-    res_sync = eng.run(3)
-    np.testing.assert_allclose(
-        [m.test_acc for m in stream_result.history],
-        [m.test_acc for m in res_sync.history],
-        atol=1e-6,
-    )
-    np.testing.assert_allclose(
-        _flat(stream_result.final_params), _flat(res_sync.final_params), atol=1e-4
-    )
+    segment sums) tracks the materialized sync engine and the plain
+    per-client oracle (``HFLSimulation``) on the same cohort draws:
+    accuracies equal, parameters allclose (the partial-sum association
+    order differs, so bit-identity is not expected)."""
+    sc, stream_result = cohort_population
+    clients, lam = list(sc.clients()), sc.assignment_matrix()
+    for oracle in (BatchedSyncEngine, HFLSimulation):
+        res = oracle(
+            clients, lam, sc.program, sc.test,
+            schedule=SCHEDULE, seed=0, cohort=spec,
+        ).run(3)
+        np.testing.assert_allclose(
+            [m.test_acc for m in stream_result.history],
+            [m.test_acc for m in res.history],
+            atol=1e-6,
+        )
+        np.testing.assert_allclose(
+            _flat(stream_result.final_params), _flat(res.final_params), atol=1e-4
+        )
 
 
 def test_reference_matches_sync_on_cohort_rounds(stream_sc, spec, materialized):
@@ -301,6 +315,62 @@ def test_stream_paging_invisible_to_results(stream_sc, spec, stream_result):
     ]
     np.testing.assert_array_equal(
         _flat(res.final_params), _flat(stream_result.final_params)
+    )
+
+
+@pytest.fixture(scope="module")
+def traced_stream(stream_sc, spec):
+    """A stream run with telemetry on: ``run(3)`` then ``run(1)``, whose
+    round replays the first call's cohort into a slab the second and third
+    rounds have partly evicted, so it pages both hits and misses."""
+    tel = Telemetry()
+    eng = StreamSyncEngine(
+        stream_sc.source, stream_sc.edge_of, stream_sc.program, stream_sc.test,
+        cohort=spec, n_edges=N_EDGES, schedule=SCHEDULE, seed=0, telemetry=tel,
+    )
+    return eng, tel, [eng.run(3), eng.run(1)]
+
+
+def test_stream_results_bit_identical_with_telemetry_on_and_off(
+    stream_sc, spec, traced_stream
+):
+    eng = StreamSyncEngine(
+        stream_sc.source, stream_sc.edge_of, stream_sc.program, stream_sc.test,
+        cohort=spec, n_edges=N_EDGES, schedule=SCHEDULE, seed=0,
+    )
+    for on, off in zip(traced_stream[2], [eng.run(3), eng.run(1)]):
+        assert off.telemetry is None and on.telemetry is not None
+        assert [(m.test_acc, m.mean_local_loss) for m in on.history] == [
+            (m.test_acc, m.mean_local_loss) for m in off.history
+        ]
+        np.testing.assert_array_equal(_flat(on.final_params), _flat(off.final_params))
+
+
+def test_page_in_attributes_account_for_the_store(traced_stream):
+    """Each ``cohort_epoch`` span carries its ``page_in`` span's figures:
+    they sum to the store's counters, and the bytes are the padded miss
+    batches' (rows padded to a power of two, floor 16, capped at the
+    slab) that ``tel.upload`` counted."""
+    eng, tel, _ = traced_stream
+    spans = tel.tracer.spans
+    epochs = [s.attrs for s in spans if s.name == "cohort_epoch"]
+    pages = [s for s in spans if s.name == "page_in"]
+    assert len(pages) == len(epochs) > 0
+    st = eng.store
+    assert st.hits > 0 and st.misses > 0
+    assert sum(a["page_misses"] for a in epochs) == st.misses
+    assert sum(a["page_hits"] for a in epochs) == st.hits
+    assert sum(p.attrs["misses"] for p in pages) == st.misses
+    row = st.n_max * int(np.prod(st._feat)) * 4 + st.n_max * 4 + 4  # x, y, slot id
+    padded = [
+        min(16 if k <= 16 else 1 << (k - 1).bit_length(), st.capacity) if k else 0
+        for k in (p.attrs["misses"] for p in pages)
+    ]
+    uploaded = [p.attrs.get("h2d_bytes", 0) for p in pages]
+    assert uploaded == [k * row for k in padded]
+    assert sum(a["page_in_bytes"] for a in epochs) == sum(uploaded)
+    assert sum(a["page_in_s"] for a in epochs) == pytest.approx(
+        sum(p.duration for p in pages)
     )
 
 
