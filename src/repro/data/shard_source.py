@@ -27,7 +27,7 @@ from typing import Iterator, List, Sequence, Tuple
 import numpy as np
 
 from repro.data.lm_stream import TokenStream
-from repro.data.synthetic_health import Dataset, make_dataset
+from repro.data.synthetic_health import Dataset, SignalBank
 from repro.utils.seedhash import keyed_hash, keyed_randint
 
 # hash stream tags: distinct draws per client must live on distinct streams
@@ -45,7 +45,9 @@ class ShardSource:
     sample feature shape), ``feat_dtype``, and implement
     ``class_counts_block(lo, hi)`` (analytic, vectorized) and
     ``shard(cid)`` (pure in ``(seed, cid)``).  Everything else — sizes,
-    dominant classes, population/edge histograms — derives from those.
+    dominant classes, population/edge histograms, :meth:`write_shards` —
+    derives from those; a source may override :meth:`write_shards` with a
+    faster writer of the same bytes.
     """
 
     seed: int
@@ -59,6 +61,16 @@ class ShardSource:
 
     def shard(self, cid: int) -> Dataset:
         raise NotImplementedError
+
+    def write_shards(self, cids: Sequence[int], x: np.ndarray, y: np.ndarray) -> None:
+        """Write ``shard(cids[k])`` into row ``k`` of ``x`` ``(rows, n_max,
+        *feat)`` and ``y`` ``(rows, n_max)``: samples ``[:n]`` of each row,
+        the rest left as they are.  Rows are written independently, so
+        callers may run disjoint row ranges on separate threads."""
+        for k, cid in enumerate(cids):
+            s = self.shard(int(cid))
+            x[k, : len(s)] = s.x
+            y[k, : len(s)] = s.y
 
     # -- derived, all chunked so 1M clients never allocates (M, K) floats ----
     def class_counts_for(self, cid: int) -> np.ndarray:
@@ -142,13 +154,15 @@ class HealthShardSource(ShardSource):
         self.dom_boost = int(dom_boost)
         self.feat_shape = (self.length, self.channels)
         self.feat_dtype = np.dtype(np.float32)
+        self._bank = SignalBank(self.n_classes, self.length, self.channels)
 
     def dominant_block(self, lo: int, hi: int) -> np.ndarray:
         """(hi-lo,) int64 dominant class per client."""
         return keyed_randint(self.seed, _S_DOM, np.arange(lo, hi), self.n_classes)
 
-    def class_counts_block(self, lo: int, hi: int) -> np.ndarray:
-        cids = np.arange(lo, hi, dtype=np.int64)
+    def class_counts_of(self, cids: np.ndarray) -> np.ndarray:
+        """(len(cids), K) int64 class counts of arbitrary client ids."""
+        cids = np.asarray(cids, dtype=np.int64)
         k = self.n_classes
         # one hash lane per (client, class): index = cid * K + class
         lanes = cids[:, None] * k + np.arange(k)[None, :]
@@ -157,13 +171,27 @@ class HealthShardSource(ShardSource):
             keyed_hash(self.seed, _S_COUNTS, lanes.ravel()).reshape(len(cids), k)
             % np.uint64(span)
         ).astype(np.int64) + self.min_per_class
-        counts[np.arange(len(cids)), self.dominant_block(lo, hi)] += self.dom_boost
+        dom = keyed_randint(self.seed, _S_DOM, cids, self.n_classes)
+        counts[np.arange(len(cids)), dom] += self.dom_boost
         return counts
+
+    def class_counts_block(self, lo: int, hi: int) -> np.ndarray:
+        return self.class_counts_of(np.arange(lo, hi, dtype=np.int64))
 
     def shard(self, cid: int) -> Dataset:
         counts = self.class_counts_for(int(cid))
         rng = np.random.default_rng((self.seed, _S_DATA, int(cid)))
-        return make_dataset(rng, counts, length=self.length, channels=self.channels)
+        return self._bank.dataset(rng, counts)
+
+    def write_shards(self, cids: Sequence[int], x: np.ndarray, y: np.ndarray) -> None:
+        """:meth:`ShardSource.write_shards` without the per-client shard:
+        the clients' counts in one vectorised hash, each client drawing
+        from its own ``default_rng((seed, _S_DATA, cid))`` as :meth:`shard`
+        does, the signals computed for blocks of clients and written
+        straight into their rows (bit-identical bytes)."""
+        cids = np.asarray(cids, dtype=np.int64)
+        rngs = [np.random.default_rng((self.seed, _S_DATA, c)) for c in cids.tolist()]
+        self._bank.write(rngs, self.class_counts_of(cids), x, y)
 
 
 class TokenShardSource(ShardSource):

@@ -27,18 +27,40 @@ avoids the store entirely.
 """
 from __future__ import annotations
 
+import os
+import time
 from collections import OrderedDict
+from concurrent.futures import ThreadPoolExecutor
 from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.data.shard_source import ShardSource
 from repro.telemetry import NULL_TELEMETRY, register_jit
 
 # past this pad blow-up the store costs more memory than it saves time;
 # callers that can fall back to host batch stacking (async engine) do so
 MAX_PADDING_RATIO = 16.0
+
+# page-in synthesis threads: the random draws and the vectorised signal
+# arithmetic release the GIL, the per-client loop holds it; on a TPU v5e
+# host synthesis scaled up to 8 threads and slowed past them
+_SYNTH_MAX_THREADS = 8
+# miss batches under this many rows are synthesised inline: handing a few
+# dozen shards to a pool costs more than it saves
+_SYNTH_INLINE_ROWS = 64
+
+
+def _synth_threads() -> int:
+    """Host threads for page-in synthesis: the CPUs this process may run on,
+    capped at ``_SYNTH_MAX_THREADS``."""
+    try:
+        n = len(os.sched_getaffinity(0))
+    except AttributeError:  # platforms without CPU affinity
+        n = os.cpu_count() or 1
+    return max(1, min(n, _SYNTH_MAX_THREADS))
 
 
 @jax.jit
@@ -157,11 +179,18 @@ class PagedShardStore:
     counters expose paging behaviour to benchmarks and tests.  Client ids
     within one ``ensure`` call must be unique (cohorts are).
 
+    Missed shards are written straight into their rows of one miss batch
+    by ``source.write_shards``; a batch of at least ``_SYNTH_INLINE_ROWS``
+    misses is split into row ranges run on a pool of host threads
+    (:func:`_synth_threads` of them, made on first use; :meth:`close` ends
+    them).  The bytes do not depend on the thread count.
+
     With an enabled ``telemetry`` each :meth:`ensure` is a ``page_in`` span
-    (attrs ``clients``, ``misses``, ``hits``; its uploads go through
+    (attrs ``clients``, ``misses``, ``hits``, ``synth_s``: the writer's wall
+    time, ``workers``: the threads it ran on; its uploads go through
     ``telemetry.upload``, so its ``h2d_bytes`` are the bytes paged in) and
-    adds ``page_in_s``, ``page_misses``, ``page_hits`` and
-    ``page_in_bytes`` to the span open around the call.
+    adds ``page_in_s``, ``page_synth_s``, ``page_misses``, ``page_hits``
+    and ``page_in_bytes`` to the span open around the call.
     """
 
     def __init__(self, source, capacity: int, n_max: "int | None" = None,
@@ -187,6 +216,8 @@ class PagedShardStore:
         self.misses = 0
         self.evictions = 0
         self.tel = telemetry
+        self._threads = _synth_threads()
+        self._pool = None  # ThreadPoolExecutor, made by the first pooled batch
 
     @classmethod
     def from_shards(cls, shards: Sequence, capacity: int):
@@ -200,6 +231,12 @@ class PagedShardStore:
     @property
     def n_clients(self) -> int:
         return len(self.sizes)
+
+    def close(self) -> None:
+        """End the synthesis threads; a later pooled batch makes new ones."""
+        if self._pool is not None:
+            self._pool.shutdown()
+            self._pool = None
 
     def _take_slot(self) -> int:
         if self._free:
@@ -221,17 +258,19 @@ class PagedShardStore:
             )
         tel = self.tel
         with tel.span("page_in", clients=len(cids)) as sp:
-            slots, misses = self._page_in(cids)
-            sp.set(misses=misses, hits=len(cids) - misses)
+            slots, misses, synth_s, workers = self._page_in(cids)
+            sp.set(misses=misses, hits=len(cids) - misses, synth_s=synth_s, workers=workers)
         if tel.enabled:
-            for key, value in (("page_in_s", sp.duration), ("page_misses", misses),
+            for key, value in (("page_in_s", sp.duration), ("page_synth_s", synth_s),
+                               ("page_misses", misses),
                                ("page_hits", len(cids) - misses),
                                ("page_in_bytes", sp.attrs.get("h2d_bytes", 0))):
                 tel.tracer.add_to_open(key, value)
         return slots
 
-    def _page_in(self, cids: np.ndarray) -> Tuple[np.ndarray, int]:
-        """(slots, misses) of :meth:`ensure`."""
+    def _page_in(self, cids: np.ndarray) -> Tuple[np.ndarray, int, float, int]:
+        """(slots, misses, synthesis seconds, synthesis threads) of
+        :meth:`ensure`."""
         slots = np.empty(len(cids), np.int64)
         missing: List[int] = []
         for p, c in enumerate(cids.tolist()):
@@ -242,40 +281,64 @@ class PagedShardStore:
                 slots[p] = s
                 self.hits += 1
                 self._lru.move_to_end(c)
-        if missing:
-            bx = np.zeros((len(missing), self.n_max) + self._feat, self._np_dtype)
-            by = np.zeros((len(missing), self.n_max), np.int32)
-            for k, p in enumerate(missing):
-                c = int(cids[p])
-                shard = self.source.shard(c)
-                n = len(shard)
-                if n > self.n_max:
-                    raise ValueError(f"shard {c} ({n} samples) exceeds n_max {self.n_max}")
-                bx[k, :n] = shard.x
-                by[k, :n] = shard.y
-                s = self._take_slot()
-                self._slot_of[c] = s
-                self._lru[c] = None
-                slots[p] = s
-                self.misses += 1
-            # one batched scatter per ensure(): host->device traffic is the
-            # round's misses only, never the population.  The miss batch is
-            # padded to a power of two (floor 16, capped at capacity) by
-            # repeating row 0 — same slot, same data, so the duplicate
-            # writes are idempotent — because a scatter compiles per
-            # distinct row count and miss counts vary every round.
-            k = len(missing)
-            kp = min(16 if k <= 16 else 1 << (k - 1).bit_length(), self.capacity)
-            ms = slots[missing]
-            if kp > k:
-                pad = kp - k
-                ms = np.concatenate([ms, np.repeat(ms[:1], pad)])
-                bx = np.concatenate([bx, np.repeat(bx[:1], pad, axis=0)])
-                by = np.concatenate([by, np.repeat(by[:1], pad, axis=0)])
-            sl = self.tel.upload(ms, np.int32)
-            self.x = self.x.at[sl].set(self.tel.upload(bx))
-            self.y = self.y.at[sl].set(self.tel.upload(by))
-        return slots, len(missing)
+        if not missing:
+            return slots, 0, 0.0, 0
+        mc = cids[missing]
+        n = self.sizes[mc]
+        if (n > self.n_max).any():
+            bad = int(np.argmax(n > self.n_max))
+            raise ValueError(
+                f"shard {int(mc[bad])} ({int(n[bad])} samples) exceeds n_max {self.n_max}"
+            )
+        for p, c in zip(missing, mc.tolist()):
+            s = self._take_slot()
+            self._slot_of[c] = s
+            self._lru[c] = None
+            slots[p] = s
+            self.misses += 1
+        # one batched scatter per ensure(): host->device traffic is the
+        # round's misses only, never the population.  The miss batch is
+        # padded to a power of two (floor 16, capped at capacity) by
+        # repeating row 0 — same slot, same data, so the duplicate writes
+        # are idempotent — because a scatter compiles per distinct row
+        # count and miss counts vary every round.
+        k = len(missing)
+        kp = min(16 if k <= 16 else 1 << (k - 1).bit_length(), self.capacity)
+        bx = np.zeros((kp, self.n_max) + self._feat, self._np_dtype)
+        by = np.zeros((kp, self.n_max), np.int32)
+        t0 = time.perf_counter()
+        workers = self._synthesise(mc, bx, by)
+        synth_s = time.perf_counter() - t0
+        bx[k:] = bx[0]
+        by[k:] = by[0]
+        ms = np.empty(kp, np.int64)
+        ms[:k] = slots[missing]
+        ms[k:] = ms[0]
+        sl = self.tel.upload(ms, np.int32)
+        self.x = self.x.at[sl].set(self.tel.upload(bx))
+        self.y = self.y.at[sl].set(self.tel.upload(by))
+        return slots, k, synth_s, workers
+
+    def _synthesise(self, cids: np.ndarray, bx: np.ndarray, by: np.ndarray) -> int:
+        """Write the shards of ``cids`` into the first rows of ``bx``/``by``;
+        returns the threads used.  Threads write disjoint row ranges."""
+        k = len(cids)
+        threads = self._threads if k >= _SYNTH_INLINE_ROWS else 1
+        if threads == 1:
+            self.source.write_shards(cids, bx, by)
+            return 1
+        if self._pool is None:
+            self._pool = ThreadPoolExecutor(threads, thread_name_prefix="page-in")
+        # a few ranges per thread, so uneven shard sizes even out
+        bounds = np.linspace(0, k, 4 * threads + 1).astype(int).tolist()
+        futures = [
+            self._pool.submit(self.source.write_shards, cids[a:b], bx[a:b], by[a:b])
+            for a, b in zip(bounds[:-1], bounds[1:])
+            if b > a
+        ]
+        for f in futures:
+            f.result()
+        return threads
 
     def gather(self, cids, idx) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """cids: (C,) client ids; idx: (C, steps, batch) in-shard indices."""
@@ -285,13 +348,13 @@ class PagedShardStore:
         )
 
 
-class _ShardListSource:
+class _ShardListSource(ShardSource):
     """Minimal ShardSource adapter over an in-memory shard list."""
 
     def __init__(self, shards: List):
         self._shards = shards
         self.n_clients = len(shards)
-        self.sizes = np.array([len(s) for s in shards], np.int64)
+        self._sizes = np.array([len(s) for s in shards], np.int64)
         feat = None
         for s in shards:
             if len(s):

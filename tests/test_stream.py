@@ -372,6 +372,15 @@ def test_page_in_attributes_account_for_the_store(traced_stream):
     assert sum(a["page_in_s"] for a in epochs) == pytest.approx(
         sum(p.duration for p in pages)
     )
+    # the 24-client cohort's misses are synthesised inline, inside the span
+    assert [p.attrs["workers"] for p in pages] == [
+        1 if p.attrs["misses"] else 0 for p in pages
+    ]
+    assert all(0 <= p.attrs["synth_s"] <= p.duration for p in pages)
+    assert all(p.attrs["synth_s"] > 0 for p in pages if p.attrs["misses"])
+    assert sum(a["page_synth_s"] for a in epochs) == pytest.approx(
+        sum(p.attrs["synth_s"] for p in pages)
+    )
 
 
 def test_lazy_lm_stream_matches_sync_engine():
