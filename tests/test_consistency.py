@@ -129,7 +129,7 @@ def golden_runs():
     return _runs()
 
 
-@pytest.mark.parametrize("path", ["sync-device", "sync-host", "async", "stream"])
+@pytest.mark.parametrize("path", ["sync-device", "reference", "async", "stream"])
 def test_golden_cnn_trajectory_pinned(golden, golden_runs, path):
     """Refactors must not silently drift the reference CNN trajectories:
     final params hash (bit-exact) and the accuracy history are pinned to

@@ -34,8 +34,8 @@ def golden_runs():
     asn = sc.assign("eara-sca").lam
     kw = dict(cloud_rounds=2, seed=0, upp=1.0)
     runs = {
-        "sync-device": sc.simulate(asn, engine="sync", pipeline="device", **kw),
-        "sync-host": sc.simulate(asn, engine="sync", pipeline="host", **kw),
+        "sync-device": sc.simulate(asn, engine="sync", **kw),
+        "reference": sc.simulate(asn, engine="reference", **kw),
         "async": sc.simulate(
             asn, engine="async", quorum=0.75, staleness_decay=0.5, **kw
         ),
