@@ -28,7 +28,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np, jax
-from benchmarks.engine_bench import _make_population
+from tools.golden_mesh import make_population
 from repro.core.hfl import HFLSchedule
 from repro.engine import BatchedSyncEngine
 from repro.engine.mesh_sim import MeshSyncEngine
@@ -36,14 +36,14 @@ from repro.engine.mesh_sim import MeshSyncEngine
 KS = %(ks)s
 TS = %(ts)s
 ROUNDS = 2
-clients, assignment, test, _lat, program, _ = _make_population(24, 8)
+clients, assignment, test, program = make_population(24, 8)
 flat = lambda p: np.concatenate(
     [np.ravel(np.asarray(l)) for l in jax.tree_util.tree_leaves(p)]
 )
 
 def run_base(t):
     eng = BatchedSyncEngine(clients, assignment, program, test,
-                            schedule=HFLSchedule(2, t), seed=0, pipeline="device")
+                            schedule=HFLSchedule(2, t), seed=0)
     return eng.run(ROUNDS, eval_every=1)
 
 def run_mesh(k, t):

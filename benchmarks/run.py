@@ -12,7 +12,6 @@ manageable.
   hfl_collectives — cross-edge collective-byte claim on mesh
   distributed — MeshSyncEngine cross-mesh parity + HLO 1/T comm accounting
   kernels — Pallas kernel micro-bench (interpret mode)
-  engine — clients/sec: sync-loop vs batched-sync vs async at M up to 512
   serving — prefill/decode tok/s, ragged overhead, hot-swap, serve round
 """
 from __future__ import annotations
@@ -30,7 +29,6 @@ def main() -> None:
         fig4_kld_distance,
         fig5_acc_rounds,
         fig6_traffic,
-        engine_bench,
         hfl_collectives,
         kernels_bench,
         roofline,
@@ -47,7 +45,6 @@ def main() -> None:
         ("hfl_collectives", hfl_collectives),
         ("distributed", distributed_bench),
         ("kernels", kernels_bench),
-        ("engine", engine_bench),
         ("serving", serving_bench),
     ]
     failures = 0

@@ -3,14 +3,16 @@
 ``ru_maxrss`` is a process-lifetime high-water mark: it never decreases, so
 a single process sweeping M = 100k then 1M would report the 100k point's
 memory as "at least whatever 1M peaked at" (or vice versa, the larger point
-hiding behind an earlier allocation).  ``engine_bench --streaming`` therefore
-spawns this module once per (M, cohort) point and reads the JSON line; each
-point's ``peak_rss_bytes`` is then the true footprint of building + running
-the streaming engine at that M and nothing else.
+hiding behind an earlier allocation).  Run this module once per (M, cohort)
+point and read its JSON line; each point's ``peak_rss_bytes`` is then the
+true footprint of building + running the streaming engine at that M and
+nothing else:
 
-The workload mirrors ``engine_bench``'s micro-CNN regime (seq 64, ~4k
-params) on a :class:`~repro.data.shard_source.HealthShardSource` population
-with striped assignment — the streaming analogue of ``_make_population``.
+    PYTHONPATH=src python -m benchmarks.streaming_point --m 100000 --cohort 64
+
+The workload is a micro-CNN regime (seq 64, ~4k params) on a
+:class:`~repro.data.shard_source.HealthShardSource` population with striped
+assignment.
 """
 from __future__ import annotations
 

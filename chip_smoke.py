@@ -238,7 +238,7 @@ def mesh_phase(k: int) -> None:
                     if eng.n_devices != k:
                         raise AssertionError(f"mesh engine runs on {eng.n_devices} devices, not {k}")
                 else:
-                    eng = BatchedSyncEngine(clients, asn, program, test, pipeline="device", **kw)
+                    eng = BatchedSyncEngine(clients, asn, program, test, **kw)
                 runs[name] = _timed(f"{name} ({precision} precision)",
                                     lambda: eng.run(rounds, eval_every=1))
         if precision == "default":

@@ -31,11 +31,9 @@ module                role
                       round time scale with cohort size, not M
 ``events``            deterministic (time, seq) heap for discrete events
 ``sync_sim``          ``BatchedSyncEngine`` — reference semantics, batched
-                      speed; ``pipeline="device"`` (default) runs a cloud
-                      round as a handful of fixed-shape device programs
-                      (edge state as one (E, D) matrix, segment-kernel
-                      aggregation), ``pipeline="host"`` keeps the PR 1
-                      host-major loop as the comparison baseline
+                      speed: a cloud round runs as a handful of
+                      fixed-shape device programs (edge state as one
+                      (E, D) matrix, segment-kernel aggregation)
 ``mesh_sim``          ``MeshSyncEngine`` — the device pipeline sharded over
                       a 1-D ``edge_mesh``: edges and their EUs' cohort rows
                       live on devices, edge FedAvg is device-local, and the
@@ -76,7 +74,7 @@ from repro.engine.flatten import BACKENDS, FlatPack, flat_mean, flat_segment_mea
 from repro.engine.mesh_sim import MeshCommLedger, MeshSyncEngine, mesh_segment_mean
 from repro.engine.store import DeviceShardStore, PagedShardStore
 from repro.engine.stream_sim import StreamSyncEngine
-from repro.engine.sync_sim import PIPELINES, BatchedSyncEngine
+from repro.engine.sync_sim import BatchedSyncEngine
 
 __all__ = [
     "AsyncHFLEngine",
@@ -90,7 +88,6 @@ __all__ = [
     "LocalJob",
     "MeshCommLedger",
     "MeshSyncEngine",
-    "PIPELINES",
     "PagedShardStore",
     "StreamCohortPlan",
     "StreamSyncEngine",

@@ -189,9 +189,8 @@ def _cohort_epoch_body(
     ``impl`` threads the
     formulation knob through (for the CNN: "gemm" — the engines' default —
     lowers the vmapped per-client convolutions to batched GEMMs instead of
-    the C-group convolution XLA:CPU serializes; "xla" is the PR 1 path,
-    kept for the benchmark baseline.  Single-formulation programs ignore
-    it.)
+    the C-group convolution XLA:CPU serializes; "xla" is the plain
+    convolution form.  Single-formulation programs ignore it.)
     """
     opt = program.make_optimizer(lr)
     opt_state = opt.init(params)
@@ -227,10 +226,8 @@ def _cohort_epoch_body(
     return params, losses.mean(axis=0)
 
 
-@partial(jax.jit, static_argnames=("program", "n_steps", "lr", "impl"), donate_argnums=(0,))
-def _cohort_epoch(
-    params, xb, yb, program: ClientProgram, n_steps: int, lr: float, impl: str = "gemm"
-):
+@partial(jax.jit, static_argnames=("program", "n_steps", "lr"), donate_argnums=(0,))
+def _cohort_epoch(params, xb, yb, program: ClientProgram, n_steps: int, lr: float):
     """Tree-major cohort epoch (see ``_cohort_epoch_body``).
 
     The params carry is donated: epochs chain ``params`` through repeated
@@ -238,7 +235,7 @@ def _cohort_epoch(
     params (and with it the Adam carry) in place instead of
     double-buffering it.
     """
-    return _cohort_epoch_body(params, xb, yb, program, n_steps, lr, impl)
+    return _cohort_epoch_body(params, xb, yb, program, n_steps, lr, "gemm")
 
 
 @partial(
@@ -334,7 +331,6 @@ def run_cohorts(
     program: ClientProgram,
     pack,
     store=None,
-    impl: str = "gemm",
     telemetry=None,
 ) -> CohortResult:
     """Train every job, batching same-shape clients into vmapped cohorts.
@@ -350,10 +346,9 @@ def run_cohorts(
     ``store`` (optional): a ``DeviceShardStore``; per-epoch batches are
     gathered on device from the padded shard array (uploading only the
     int32 sample indices) instead of ``np.stack``-ing numpy shards on the
-    host every epoch.  ``impl`` is the conv formulation for the cohort
-    step ("gemm" | "xla", see ``_cohort_epoch_body``).  ``telemetry``
-    (optional ``repro.telemetry.Telemetry``) records one ``cohort_epoch``
-    span per cohort with the analytic FLOPs/bytes of the jitted epoch.
+    host every epoch.  ``telemetry`` (optional
+    ``repro.telemetry.Telemetry``) records one ``cohort_epoch`` span per
+    cohort with the analytic FLOPs/bytes of the jitted epoch.
     """
     tel = telemetry if telemetry is not None else NULL_TELEMETRY
     program = program if program is not None else jobs[0].client.program
@@ -403,11 +398,11 @@ def run_cohorts(
                 if e == 0:
                     cost = tel.jit_cost(
                         "cohort_epoch", _cohort_epoch,
-                        params, xb, yb, prog, steps, lr, impl,
+                        params, xb, yb, prog, steps, lr,
                     )
                     if cost:
                         sp.set(**cost)
-                params, loss = _cohort_epoch(params, xb, yb, prog, steps, lr, impl)
+                params, loss = _cohort_epoch(params, xb, yb, prog, steps, lr)
             mats[prog].append(pack_of(prog).ravel_batched(params))
         loss = np.asarray(loss)
         for c, job in enumerate(members):
@@ -448,7 +443,7 @@ class _PlanGroup:
 
 
 class CohortPlan:
-    """Static cohort grouping for the device pipeline.
+    """Static cohort grouping for the sync engines' rounds.
 
     Which cohort a client falls into depends only on its shard size and its
     hyperparameters — the full (steps, local-epochs, batch-size, lr) tuple,
@@ -461,11 +456,9 @@ class CohortPlan:
 
     Per round, :meth:`draw` only consumes the numpy RNG stream —
     draw-for-draw like ``FLClient.local_update`` and in global client
-    order, which is what keeps fixed-seed device-pipeline runs on the
-    reference trajectory regardless of how clients are grouped — and fills
-    per-group index tensors.  This replaces the per-round
-    ``LocalJob``/``make_job`` object churn of the host pipeline (~2x less
-    host time per round at M=512).
+    order, which is what keeps fixed-seed engine runs on the reference
+    trajectory regardless of how clients are grouped — and fills per-group
+    index tensors, with no per-round ``LocalJob``/``make_job`` objects.
 
     The plan keys cohorts on the clients' ``program`` as well: clients only
     stack into one (C, D) cohort when they train the SAME ``ClientProgram``

@@ -82,9 +82,9 @@ def draw_public_batches(rng, sizes, spec: DistillSpec):
     """Per-edge public-shard sample indices for one distillation fuse.
 
     One ``(steps, batch)`` integer draw per edge, in edge order — the
-    reference simulator and both engine pipelines replicate this stream
-    draw-for-draw, which is what keeps their fuses on identical batches.
-    Returns an ``(E, steps, batch)`` int32 index tensor.
+    reference simulator and the sync and async engines replicate this
+    stream draw-for-draw, which is what keeps their fuses on identical
+    batches.  Returns an ``(E, steps, batch)`` int32 index tensor.
     """
     return np.stack(
         [rng.integers(0, int(n), (spec.steps, spec.batch)) for n in sizes]

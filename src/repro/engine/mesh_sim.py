@@ -170,9 +170,8 @@ class MeshSyncEngine(BatchedSyncEngine):
         super().__init__(
             clients, assignment, program, test, schedule=schedule, seed=seed,
             upp=upp, track_divergence=track_divergence, central_batch=central_batch,
-            cost_latency=cost_latency, backend=backend, pipeline="device",
-            telemetry=telemetry, cohort=cohort, server_momentum=server_momentum,
-            serve=serve,
+            cost_latency=cost_latency, backend=backend, telemetry=telemetry,
+            cohort=cohort, server_momentum=server_momentum, serve=serve,
         )
         if len(self.groups) > 1:
             raise ValueError(

@@ -21,9 +21,8 @@ resamples within the shard), so the zero padding rows are never read.
 Padding is to the LARGEST shard: memory is O(M * n_max).  With the IoT
 populations this engine targets (many small, similar shards) the overhead
 is bounded, but one pathologically large shard inflates the store M-fold —
-``padding_ratio`` reports the blow-up, the async engine skips the store
-past ``MAX_PADDING_RATIO``, and the sync engine's ``pipeline="host"``
-avoids the store entirely.
+``padding_ratio`` reports the blow-up, and the async engine skips the store
+past ``MAX_PADDING_RATIO``.
 """
 from __future__ import annotations
 

@@ -4,34 +4,25 @@ Same semantics as ``federated.simulation.HFLSimulation`` — the same RNG
 stream, participation sampling, DCA starts, schedule, and accounting — but
 the hot loop is restructured for scale.  The engine is model-agnostic: it
 trains whatever ``ClientProgram`` (``federated.programs``) the clients
-carry — the paper's CNN, the MLP, or the transformer-LM — through the same
-flat-buffer pipelines:
+carry — the paper's CNN, the MLP, or the transformer-LM — through one
+device-resident round pipeline:
 
   * local training: one jitted cohort call per same-shape client group
-    (``engine.cohort``) instead of one jitted call per client;
+    (``engine.cohort``) instead of one jitted call per client, on batches
+    gathered on device from a ``DeviceShardStore`` by int32 indices;
   * model state is *flat-major*: clients exchange (D,) rows, edge models
-    live in one (E, D) device matrix, and FedAvg runs on (N, D) matrices
-    through the Pallas kernels (``backend="pallas"``) or plain-XLA
-    contractions (``backend="reference"``);
+    live in one (E, D) device matrix, and every edge's FedAvg is ONE
+    ``flat_segment_mean`` call over the (P, D) membership-pair matrix
+    (segments = edges; per-round participation travels in the weights so
+    shapes never change) through the Pallas kernels (``backend="pallas"``)
+    or plain-XLA contractions (``backend="reference"``);
+  * DCA start averaging is one segment call with segments = clients, and
+    the cloud mean reduces the (E, D) edge matrix directly: O(1) device
+    dispatches per round, no per-edge-size recompiles;
   * uploads optionally pass through a ``CompressionSpec`` applied to the
     flat model delta (global top-k over all parameters, vs the reference
     simulator's per-leaf top-k) with per-client error feedback, and the
     accountant then counts compressed bits.
-
-Two pipelines (``pipeline=``):
-
-  * ``"device"`` (default) — the round executes as a handful of
-    fixed-shape device programs: client shards live in a
-    ``DeviceShardStore`` (batches gathered on device from int32 indices),
-    every edge's aggregation is ONE ``flat_segment_mean`` call over the
-    (P, D) membership-pair matrix (segments = edges; per-round
-    participation travels in the weights so shapes never change), DCA
-    start averaging is one segment call with segments = clients, and the
-    cloud mean reduces the (E, D) edge matrix directly.  O(1) device
-    dispatches per round instead of O(E), no per-edge-size recompiles.
-  * ``"host"`` — the PR 1 host-major loop (per-edge ``flat_mean`` calls,
-    numpy batch stacking), kept as the comparison baseline for
-    ``benchmarks/engine_bench.py`` and the equivalence tests.
 
 Heterogeneous-model federation (ISSUE 5): clients under one edge may carry
 DIFFERENT programs.  Every structure above becomes per-ARCHITECTURE-group:
@@ -63,13 +54,7 @@ import numpy as np
 from repro.core.compression import CompressionSpec
 from repro.core.hfl import CommAccountant, HFLSchedule, WallClock, weight_divergence
 from repro.data.synthetic_health import Dataset
-from repro.engine.cohort import (
-    CohortPlan,
-    _cohort_epoch_flat,
-    build_group_state,
-    make_job,
-    run_cohorts,
-)
+from repro.engine.cohort import CohortPlan, _cohort_epoch_flat, build_group_state
 from repro.engine.distill import (
     DistillSpec,
     check_distillable,
@@ -98,8 +83,6 @@ from repro.telemetry import NULL_TELEMETRY, coerce_telemetry, register_jit
 from repro.telemetry.report import CommDelta
 from repro.utils.tree import tree_size_bytes
 
-PIPELINES = ("device", "host")
-
 
 @partial(jax.jit, static_argnames=("n_segments", "backend"))
 def _segment_agg_keep(upd, seg_ids, weights, has, prev, n_segments: int, backend: str):
@@ -124,9 +107,10 @@ class BatchedSyncEngine:
       carry programs that DIFFER from it (and from each other): the engine
       partitions the population into architecture groups and runs every
       pipeline stage per group.
-    * ``pipeline`` — ``"device"`` (default: shard store + fused segment
-      aggregation, O(1) dispatches per round) | ``"host"`` (the PR 1
-      host-major loop, kept as benchmark baseline).
+    * ``pipeline`` — only ``"device"``, the one round pipeline.  The
+      argument stays because the chip benchmark's eager cell
+      (``benchmarks/chip``) still passes it; it goes once that caller
+      stops.
     * ``backend`` — flat-buffer aggregation path: ``"pallas"`` (kernels;
       tiny-N and off-TPU calls route to jitted contractions) |
       ``"reference"`` (plain-XLA contractions).
@@ -171,8 +155,8 @@ class BatchedSyncEngine:
         server_momentum: float = 0.0,
         serve=None,
     ):
-        if pipeline not in PIPELINES:
-            raise ValueError(f"pipeline must be one of {PIPELINES}, got {pipeline!r}")
+        if pipeline != "device":
+            raise ValueError(f"pipeline must be 'device', got {pipeline!r}")
         if backend not in BACKENDS:
             raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
         self.tel = coerce_telemetry(telemetry) or NULL_TELEMETRY
@@ -199,7 +183,6 @@ class BatchedSyncEngine:
         self.params = self.program.init(jax.random.PRNGKey(seed))
         self.backend = backend
         self.compression = compression
-        self.pipeline = pipeline
         self.pack = FlatPack(self.params)
         # architecture groups: one of everything below per distinct program
         gs = build_group_state(
@@ -252,8 +235,8 @@ class BatchedSyncEngine:
         self._errors: Dict[int, object] = {}
         self._data_sizes = np.array([c.data_size for c in clients], np.float32)
         self._build_pair_structure(assignment)
-        self.store = DeviceShardStore(clients) if pipeline == "device" else None
-        self._plan = CohortPlan(clients, self.program) if pipeline == "device" else None
+        self.store = DeviceShardStore(clients)
+        self._plan = CohortPlan(clients, self.program)
         if self.tel.enabled:
             for g, prog in enumerate(self.groups):
                 self.tel.metrics.set_gauge(
@@ -306,11 +289,6 @@ class BatchedSyncEngine:
             if self.tel.enabled:
                 self.tel.metrics.inc("faults_reassigned", int(len(changed)))
 
-    def _mean(self, rows: List[jnp.ndarray], weights) -> jnp.ndarray:
-        return flat_mean(
-            jnp.stack(rows), np.asarray(weights, np.float32), backend=self.backend
-        )
-
     def _edge_account(self, participating: np.ndarray, failed=None) -> None:
         """Charge one edge round: per architecture group, each group's
         clients pay that group's uplink/downlink payload (one masked
@@ -349,9 +327,8 @@ class BatchedSyncEngine:
     def _draw_participation(self, m: int) -> np.ndarray:
         """This round's (M,) participation mask.  Cohort sampling reads the
         keyed side channel (engine RNG untouched); the UPP path consumes the
-        engine RNG draw-for-draw like the reference simulator.  Shared by
-        every sync pipeline (host / device / mesh) so they stay on one RNG
-        stream."""
+        engine RNG draw-for-draw like the reference simulator.  Shared with
+        the mesh engine so both stay on one RNG stream."""
         if self.cohort is not None:
             return self.cohort.mask(self._round, self._er, assignment=self.assignment)
         participating = self.rng.random(m) < self.upp
@@ -375,7 +352,7 @@ class BatchedSyncEngine:
             weights = self.tel.upload(weights)
         return flat_mean(edge_mat, weights, backend=self.backend)
 
-    # -- one edge round, device pipeline --------------------------------------
+    # -- one edge round -------------------------------------------------------
     def _client_starts(self, edge_mat: jnp.ndarray) -> jnp.ndarray:
         """(M, D) per-client DCA start rows from the (E, D) edge matrix.
 
@@ -560,88 +537,10 @@ class BatchedSyncEngine:
         self._edge_account(participating, failed)
         return edge_mats, loss_chunks
 
-    # -- one edge round, host pipeline --------------------------------------
-    def _edge_round(self, edge_rows: List[List[jnp.ndarray]]) -> List[float]:
-        """The PR 1 host-major round, preserved (host batch stacking,
-        per-edge ``flat_mean`` loop, XLA-conv cohort step) as the benchmark
-        baseline and equivalence-test counterpart.  ``edge_rows[g][j]`` is
-        edge j's model for architecture group g."""
-        m, n = self.assignment.shape
-        with self.tel.span("assignment", round=self._round, engine="sync-host"):
-            participating = self._draw_participation(m)
-            failed = None
-            if self.faults is not None:
-                participating &= self.faults.participation(self._round)
-                failed = (
-                    self.faults.failed_uploads(self._round, self._er)
-                    & participating
-                    & self._has_edge
-                )
-                if self.tel.enabled:
-                    self.tel.metrics.inc("faults_dropped", int(failed.sum()))
-            # job prep consumes the RNG in client order, mirroring the reference
-            jobs, job_edges = [], []
-            for i, cl in enumerate(self.clients):
-                edges = np.nonzero(self.assignment[i])[0]
-                if len(edges) == 0 or not participating[i]:
-                    continue
-                rows = edge_rows[self.group_of[i]]
-                # a DCA client starts from the average of its edges' models
-                start = rows[edges[0]] if len(edges) == 1 else self._mean(
-                    [rows[j] for j in edges], [1.0] * len(edges)
-                )
-                jobs.append(make_job(cl, start, self.rng, epochs=self.schedule.local_steps))
-                job_edges.append(edges)
-        trained = run_cohorts(
-            jobs, self.program, self.pack, impl="xla", telemetry=self.tel
-        )
-        compressing = self.compression is not None and self.compression.kind != "none"
-        losses = []
-        new_cids: Dict[tuple, List[int]] = {}
-        new_rows: Dict[tuple, List[jnp.ndarray]] = {}
-        new_sizes: Dict[tuple, List[float]] = {}
-        for job, edges in zip(jobs, job_edges):
-            cid = job.client.cid
-            gi = self.group_of[cid]
-            losses.append(trained.loss[cid])
-            if failed is not None and failed[cid]:
-                continue  # trained, transmitted, lost: masked out of FedAvg
-            quantizing = not compressing and job.client.program.quantizes_upload
-            transforming = compressing or quantizing
-            if compressing:
-                row = compress_flat_upload(
-                    self.compression, self._errors, cid, job.start_flat, trained.row(cid)
-                )
-            elif quantizing:
-                row = job.client.program.quantize_upload(job.start_flat, trained.row(cid))
-            for j in edges:
-                new_cids.setdefault((j, gi), []).append(cid)
-                if transforming:
-                    new_rows.setdefault((j, gi), []).append(row)
-                new_sizes.setdefault((j, gi), []).append(job.client.data_size)
-        with self.tel.span(
-            "edge_aggregate", round=self._round, engine="sync-host",
-            edges=len(new_cids),
-        ):
-            for (j, gi), cids in new_cids.items():
-                # untransformed fast path: one gather from the cohort matrix
-                mat = (
-                    jnp.stack(new_rows[(j, gi)])
-                    if (j, gi) in new_rows
-                    else trained.gather(cids)
-                )
-                edge_rows[gi][j] = flat_mean(
-                    mat, np.asarray(new_sizes[(j, gi)], np.float32), backend=self.backend
-                )
-                if self._edge_got is not None:
-                    self._edge_got[gi][j] = True
-        self._edge_account(participating, failed)
-        return losses
-
     # -- distillation fuse ----------------------------------------------------
     def _kd_fuse_device(self, edge_mats: List[jnp.ndarray]) -> List[jnp.ndarray]:
-        """Fuse every edge's per-group models on its public shard (device
-        pipeline: batches gathered from the public store in one call)."""
+        """Fuse every edge's per-group models on its public shard (batches
+        gathered from the public store in one call)."""
         n = self.assignment.shape[1]
         idx = draw_public_batches(self.rng, self.public_store.sizes, self.distill)
         xb = self.public_store.gather(np.arange(n), idx)[0]  # (E, steps, B, *feat)
@@ -650,12 +549,6 @@ class BatchedSyncEngine:
             self.distill, telemetry=self.tel,
         )
         return fused
-
-    def _kd_fuse_host(self, edge_rows: List[List[jnp.ndarray]]) -> List[List[jnp.ndarray]]:
-        """Host-pipeline counterpart: same flat fuse over stacked rows."""
-        mats = [jnp.stack(rows) for rows in edge_rows]
-        fused = self._kd_fuse_device(mats)
-        return [[mat[j] for j in range(mat.shape[0])] for mat in fused]
 
     def _central_step(self):
         self.central_params = central_reference_step(
@@ -699,7 +592,6 @@ class BatchedSyncEngine:
         ]
         edge_sizes = group_edge_sizes(self.clients, self.assignment, self.group_of)
         cloud_bits = None if n_groups == 1 else float(sum(self._group_bits))
-        engine_name = f"sync-{self.pipeline}"
         comm = CommDelta(self.accountant) if self.tel.enabled else None
         wall_accum = sim_accum = 0.0
         for b in range(1, cloud_rounds + 1):
@@ -709,7 +601,7 @@ class BatchedSyncEngine:
             acc = None
             losses: List = []
             gc0 = self.tel.gc_seconds
-            with self.tel.span("cloud_round", round=b, engine=engine_name) as rsp:
+            with self.tel.span("cloud_round", round=b, engine="sync-device") as rsp:
                 if self.faults is not None:
                     self._maybe_repair(b)
                     if self.faults.spec.reassign:
@@ -722,83 +614,49 @@ class BatchedSyncEngine:
                     if self.clock is not None:
                         # the straggler model reads the round's faded channel
                         self.clock.latency = self.faults.latency(b)
-                if self.pipeline == "device":
-                    edge_mats = self._broadcast_rows(global_rows, n)
-                    for k in range(self.schedule.edge_per_cloud):
-                        self._er = k + 1
-                        edge_mats, chunks = self._edge_round_device(edge_mats)
-                        losses += chunks  # per-cohort (C,) arrays, still on device
-                    if self.distill is not None:
-                        edge_mats = self._kd_fuse_device(edge_mats)
-                    # cloud FedAvg straight off the (E, D) matrices: static
-                    # shape, no per-round stacking; one reduction per group
-                    with self.tel.span(
-                        "cloud_reduce", round=b, groups=n_groups, edges=n
-                    ) as sp:
-                        cost = self.tel.jit_cost(
-                            "cloud_reduce",
-                            self._cloud_mean,
-                            edge_mats[0], np.asarray(edge_sizes[0], np.float32),
-                        )
-                        if cost:
-                            sp.set(**cost)
-                        if self.faults is not None:
-                            # degraded-mode reduction: starved edges (no
-                            # upload all cloud round) weigh zero; a fully
-                            # starved group keeps its global row
-                            gw = [
-                                np.asarray(edge_sizes[g], np.float32)
-                                * self._edge_got[g]
-                                for g in range(n_groups)
-                            ]
-                            new_rows = [
-                                self._cloud_mean(edge_mats[g], gw[g])
-                                if gw[g].any()
-                                else global_rows[g]
-                                for g in range(n_groups)
-                            ]
-                        else:
-                            new_rows = [
-                                self._cloud_mean(edge_mats[g], edge_sizes[g])
-                                for g in range(n_groups)
-                            ]
-                        global_rows = self._apply_server_momentum(
-                            global_rows, new_rows
-                        )
-                    with self.tel.span("fetch", what="losses"):
-                        losses = (
-                            list(np.concatenate([np.asarray(c) for c in losses]))
-                            if losses
-                            else []
-                        )
-                else:
-                    edge_rows = [[row] * n for row in global_rows]
-                    for k in range(self.schedule.edge_per_cloud):
-                        self._er = k + 1
-                        losses += self._edge_round(edge_rows)
-                    if self.distill is not None:
-                        edge_rows = self._kd_fuse_host(edge_rows)
-                    with self.tel.span("cloud_reduce", round=b, groups=n_groups, edges=n):
-                        if self.faults is not None:
-                            gw = [
-                                np.asarray(edge_sizes[g], np.float32)
-                                * self._edge_got[g]
-                                for g in range(n_groups)
-                            ]
-                            new_rows = [
-                                self._mean(edge_rows[g], gw[g])
-                                if gw[g].any()
-                                else global_rows[g]
-                                for g in range(n_groups)
-                            ]
-                        else:
-                            new_rows = [
-                                self._mean(edge_rows[g], edge_sizes[g])
-                                for g in range(n_groups)
-                            ]
-                        global_rows = self._apply_server_momentum(
-                            global_rows, new_rows
-                        )
+                edge_mats = self._broadcast_rows(global_rows, n)
+                for k in range(self.schedule.edge_per_cloud):
+                    self._er = k + 1
+                    edge_mats, chunks = self._edge_round_device(edge_mats)
+                    losses += chunks  # per-cohort (C,) arrays, still on device
+                if self.distill is not None:
+                    edge_mats = self._kd_fuse_device(edge_mats)
+                # cloud FedAvg straight off the (E, D) matrices: static shape,
+                # no per-round stacking; one reduction per group
+                with self.tel.span("cloud_reduce", round=b, groups=n_groups, edges=n) as sp:
+                    cost = self.tel.jit_cost(
+                        "cloud_reduce",
+                        self._cloud_mean,
+                        edge_mats[0], np.asarray(edge_sizes[0], np.float32),
+                    )
+                    if cost:
+                        sp.set(**cost)
+                    if self.faults is not None:
+                        # degraded-mode reduction: starved edges (no upload all
+                        # cloud round) weigh zero; a fully starved group keeps
+                        # its global row
+                        gw = [
+                            np.asarray(edge_sizes[g], np.float32) * self._edge_got[g]
+                            for g in range(n_groups)
+                        ]
+                        new_rows = [
+                            self._cloud_mean(edge_mats[g], gw[g])
+                            if gw[g].any()
+                            else global_rows[g]
+                            for g in range(n_groups)
+                        ]
+                    else:
+                        new_rows = [
+                            self._cloud_mean(edge_mats[g], edge_sizes[g])
+                            for g in range(n_groups)
+                        ]
+                    global_rows = self._apply_server_momentum(global_rows, new_rows)
+                with self.tel.span("fetch", what="losses"):
+                    losses = (
+                        list(np.concatenate([np.asarray(c) for c in losses]))
+                        if losses
+                        else []
+                    )
                 self.accountant.on_cloud_sync(n, bits=cloud_bits)
                 if self.clock is not None:
                     self.clock.on_cloud_sync()
@@ -847,7 +705,7 @@ class BatchedSyncEngine:
                 if acc is not None:
                     self.tel.metrics.set_gauge("eval_acc", acc)
                 self.tel.on_round(
-                    engine=engine_name, round=b, acc=acc,
+                    engine="sync-device", round=b, acc=acc,
                     loss=float(np.mean(losses)) if losses else None,
                     wall_s=round_wall,
                     sim_s=round_sim if self.clock is not None else None,

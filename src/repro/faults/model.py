@@ -10,8 +10,8 @@ The model is split in two:
   RNG stream.  That keeps two invariants: (1) the engines' draw-for-draw RNG
   parity (participation + batch draws) is untouched, so ``faults=None`` runs
   stay bit-identical to the fault-free engines; (2) the churn/failure
-  schedule is identical across reference / sync-device / sync-host / async
-  for one spec, whatever each engine's internal draw order is.
+  schedule is identical across the reference, sync and async engines for
+  one spec, whatever each engine's internal draw order is.
 
 * :class:`FaultState` — the mutable per-run runtime built from a spec plus
   the scenario's physical layer (``wireless.channel``).  It re-evaluates the

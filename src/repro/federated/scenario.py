@@ -139,7 +139,6 @@ class Scenario:
         compression=None,
         staleness_decay: float = 0.5,
         quorum: float = 0.75,
-        pipeline: str = "device",
         distill=None,
         faults=None,
         telemetry=None,
@@ -157,13 +156,10 @@ class Scenario:
                                 (extra knobs: ``staleness_decay`` in
                                 [0, 1], ``quorum`` in (0, 1]).
         backend:  aggregation path for the engines ("pallas" | "reference").
-        pipeline: sync-engine round pipeline ("device" — fixed-shape
-                  segment-kernel programs, shard store; "host" — the PR 1
-                  host-major loop; "mesh" — the device pipeline sharded
-                  over an ``edge_mesh`` via ``MeshSyncEngine``).
         mesh:     None | device count | ``jax.sharding.Mesh`` with an
-                  ``"edge"`` axis — selects the mesh engine (implies
-                  ``pipeline="mesh"``); the edge count must divide by the
+                  ``"edge"`` axis — with ``engine="sync"``, runs the sync
+                  engine's round sharded over that ``edge_mesh``
+                  (``MeshSyncEngine``); the edge count must divide by the
                   mesh size.  The returned ``SimResult`` then carries the
                   engine's HLO collective accounting as ``.comm_report``.
         compression: None | ``core.compression.CompressionSpec`` (kinds
@@ -245,7 +241,7 @@ class Scenario:
             return self._simulate(
                 assignment, cloud_rounds, schedule, seed, upp, track_divergence,
                 eval_every, wall_clock, engine, backend, compression,
-                staleness_decay, quorum, pipeline, distill, fault_state, tel,
+                staleness_decay, quorum, distill, fault_state, tel,
                 cohort, server_momentum, mesh, serve_state,
             )
         finally:
@@ -267,7 +263,6 @@ class Scenario:
         compression,
         staleness_decay,
         quorum,
-        pipeline,
         distill,
         faults,
         telemetry,
@@ -323,7 +318,7 @@ class Scenario:
             if wall_clock:
                 res.wall_seconds = sim.clock.seconds
             return res
-        if engine == "sync" and (pipeline == "mesh" or mesh is not None):
+        if engine == "sync" and mesh is not None:
             from repro.engine import MeshSyncEngine
 
             sim = MeshSyncEngine(
@@ -363,7 +358,6 @@ class Scenario:
                 cost_latency=self.cost.latency if wall_clock else None,
                 backend=backend,
                 compression=compression,
-                pipeline=pipeline,
                 public_shards=self.public,
                 distill=distill,
                 faults=faults,

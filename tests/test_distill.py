@@ -198,16 +198,10 @@ def test_homogeneous_model_mix_bit_identical_to_model():
 # -- end-to-end: 2 cloud rounds on every engine ------------------------------
 def test_mixed_two_rounds_all_engines(mix_scenario, mix_assignment):
     """Acceptance: model_mix trains 2+ cloud rounds with FINITE loss on
-    sync-device, sync-host, AND async, and the final params carry one tree
-    per architecture."""
-    for engine, kw in [
-        ("sync", dict(pipeline="device")),
-        ("sync", dict(pipeline="host")),
-        ("async", {}),
-    ]:
-        res = mix_scenario.simulate(
-            mix_assignment, cloud_rounds=2, seed=0, engine=engine, **kw
-        )
+    the sync AND async engines, and the final params carry one tree per
+    architecture."""
+    for engine in ("sync", "async"):
+        res = mix_scenario.simulate(mix_assignment, cloud_rounds=2, seed=0, engine=engine)
         assert len(res.history) == 2
         for m in res.history:
             assert np.isfinite(m.mean_local_loss)
@@ -216,27 +210,24 @@ def test_mixed_two_rounds_all_engines(mix_scenario, mix_assignment):
 
 
 def test_mixed_engine_matches_reference(mix_scenario, mix_assignment):
-    """Both sync pipelines reproduce the hetero reference simulator's
+    """The sync engine reproduces the hetero reference simulator's
     trajectory (the reference trains each client with its own program and
     fuses with the tree-form distillation — this parity is the end-to-end
     correctness guarantee for the group-aware engine paths)."""
     ref = mix_scenario.simulate(
         mix_assignment, cloud_rounds=2, schedule=HFLSchedule(2, 1), seed=0
     )
-    for pipeline in ("device", "host"):
-        eng = mix_scenario.simulate(
-            mix_assignment, cloud_rounds=2, schedule=HFLSchedule(2, 1), seed=0,
-            engine="sync", pipeline=pipeline,
-        )
-        for mr, me in zip(ref.history, eng.history):
-            assert me.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
-            assert me.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=5e-3)
-        assert eng.accountant.eu_bits_up == pytest.approx(ref.accountant.eu_bits_up)
-        assert eng.accountant.eu_bits_down == pytest.approx(ref.accountant.eu_bits_down)
-        assert eng.accountant.edge_rounds == ref.accountant.edge_rounds
-        assert eng.accountant.edge_cloud_bits == pytest.approx(
-            ref.accountant.edge_cloud_bits
-        )
+    eng = mix_scenario.simulate(
+        mix_assignment, cloud_rounds=2, schedule=HFLSchedule(2, 1), seed=0,
+        engine="sync",
+    )
+    for mr, me in zip(ref.history, eng.history):
+        assert me.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
+        assert me.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=5e-3)
+    assert eng.accountant.eu_bits_up == pytest.approx(ref.accountant.eu_bits_up)
+    assert eng.accountant.eu_bits_down == pytest.approx(ref.accountant.eu_bits_down)
+    assert eng.accountant.edge_rounds == ref.accountant.edge_rounds
+    assert eng.accountant.edge_cloud_bits == pytest.approx(ref.accountant.edge_cloud_bits)
 
 
 def test_mixed_accounting_per_group(mix_scenario, mix_assignment):

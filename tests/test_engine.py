@@ -180,39 +180,36 @@ def test_sync_engine_matches_reference(scenario, assignment, schedule):
 
 @pytest.mark.parametrize("schedule,upp", [(HFLSchedule(1, 1), 1.0), (HFLSchedule(2, 2), 0.6)])
 def test_sync_engine_device_pipeline_matches_host(scenario, assignment, schedule, upp):
-    """Old path vs segment path: the PR 1 host-major loop and the
-    device-resident pipeline consume the same RNG stream and must produce
-    the same trajectory (segment aggregation reassociates the FedAvg sums,
-    so params agree to float tolerance, accuracy to 1e-6)."""
-    runs = {}
-    for pipeline in ("host", "device"):
-        runs[pipeline] = scenario.simulate(
-            assignment, cloud_rounds=2, schedule=schedule, seed=11, upp=upp,
-            engine="sync", pipeline=pipeline,
-        )
-    host, dev = runs["host"], runs["device"]
-    for mh, md in zip(host.history, dev.history):
-        assert md.test_acc == pytest.approx(mh.test_acc, abs=1e-6)
-        assert md.mean_local_loss == pytest.approx(mh.mean_local_loss, abs=5e-3)
-    assert dev.accountant.eu_traffic_bits() == host.accountant.eu_traffic_bits()
-    for a, b in zip(jax.tree.leaves(host.final_params), jax.tree.leaves(dev.final_params)):
+    """The device-resident round and the reference simulator consume the
+    same RNG stream and must produce the same trajectory on seed 11, under
+    full and partial participation (segment aggregation reassociates the
+    FedAvg sums, so params agree to float tolerance, accuracy to 1e-6).
+    The name is kept from when the sync engine had a host pipeline to
+    compare with."""
+    ref = scenario.simulate(assignment, cloud_rounds=2, schedule=schedule, seed=11, upp=upp)
+    dev = scenario.simulate(
+        assignment, cloud_rounds=2, schedule=schedule, seed=11, upp=upp,
+        engine="sync",
+    )
+    for mr, md in zip(ref.history, dev.history):
+        assert md.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
+        assert md.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=5e-3)
+    assert dev.accountant.eu_traffic_bits() == ref.accountant.eu_traffic_bits()
+    for a, b in zip(jax.tree.leaves(ref.final_params), jax.tree.leaves(dev.final_params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-3)
 
 
 def test_sync_engine_device_pipeline_dual_connectivity(scenario):
     """DCA rows (clients on 2 edges) exercise the segment-mean start path;
-    both pipelines and the reference simulator must agree."""
+    the engine and the reference simulator must agree."""
     m = len(scenario.clients)
     n = scenario.n_edges
     asn = np.zeros((m, n))
     asn[np.arange(m), np.arange(m) % n] = 1.0
     asn[: m // 2, (np.arange(m // 2) + 1) % n] = 1.0  # half the EUs dual-homed
     ref = scenario.simulate(asn, cloud_rounds=1, seed=5, upp=1.0)
-    for pipeline in ("host", "device"):
-        eng = scenario.simulate(
-            asn, cloud_rounds=1, seed=5, upp=1.0, engine="sync", pipeline=pipeline
-        )
-        assert eng.final_accuracy() == pytest.approx(ref.final_accuracy(), abs=1e-6)
+    eng = scenario.simulate(asn, cloud_rounds=1, seed=5, upp=1.0, engine="sync")
+    assert eng.final_accuracy() == pytest.approx(ref.final_accuracy(), abs=1e-6)
 
 
 def test_sync_engine_matches_reference_with_upp(scenario, assignment):

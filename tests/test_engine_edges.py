@@ -30,17 +30,14 @@ def _single_client_edge_assignment(m, n):
 
 def test_single_client_edge_matches_reference(scenario):
     """An edge with one member degenerates FedAvg to that client's upload;
-    both sync pipelines must still track the reference exactly."""
+    the sync engine must still track the reference exactly."""
     m, n = len(scenario.clients), scenario.n_edges
     asn = _single_client_edge_assignment(m, n)
     ref = scenario.simulate(asn, cloud_rounds=2, seed=2, upp=1.0)
-    for pipeline in ("device", "host"):
-        eng = scenario.simulate(
-            asn, cloud_rounds=2, seed=2, upp=1.0, engine="sync", pipeline=pipeline
-        )
-        for mr, me in zip(ref.history, eng.history):
-            assert me.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
-            assert me.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=5e-3)
+    eng = scenario.simulate(asn, cloud_rounds=2, seed=2, upp=1.0, engine="sync")
+    for mr, me in zip(ref.history, eng.history):
+        assert me.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
+        assert me.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=5e-3)
 
 
 def test_async_quorum_one_aggregates_per_upload(scenario):
@@ -72,11 +69,8 @@ def test_empty_secondary_edge_dca_membership(scenario):
     asn[np.arange(m), np.arange(m) % (n - 1)] = 1.0  # edge n-1 never assigned
     asn[: m // 2, 0] = 1.0  # plus some dual-connectivity rows
     ref = scenario.simulate(asn, cloud_rounds=1, seed=4, upp=1.0)
-    for pipeline in ("device", "host"):
-        eng = scenario.simulate(
-            asn, cloud_rounds=1, seed=4, upp=1.0, engine="sync", pipeline=pipeline
-        )
-        assert eng.final_accuracy() == pytest.approx(ref.final_accuracy(), abs=1e-6)
+    eng = scenario.simulate(asn, cloud_rounds=1, seed=4, upp=1.0, engine="sync")
+    assert eng.final_accuracy() == pytest.approx(ref.final_accuracy(), abs=1e-6)
     lat = np.full((m, n), 0.01)
     asy = AsyncHFLEngine(
         scenario.clients, asn, scenario.program, scenario.test, latency=lat,

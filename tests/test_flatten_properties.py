@@ -64,7 +64,9 @@ def test_flatpack_batched_round_trip_exact(shapes, cohort, seed):
 @settings(max_examples=20, deadline=None)
 @given(
     _shapes,
-    st.sampled_from(["float16", "float64", "int32", "int8"]),
+    # dtypes that stay distinct from the float32 leaves with x64 off
+    # ("float64" would be made float32, leaving the tree uniform)
+    st.sampled_from(["float16", "bfloat16", "int32", "int8"]),
     st.integers(0, 100),
 )
 def test_flatpack_rejects_mixed_dtype_trees(shapes, other_dtype, seed):

@@ -142,17 +142,16 @@ def _params_hash(tree) -> str:
 
 @pytest.fixture(scope="module")
 def mesh_pop():
-    from benchmarks.engine_bench import _make_population
+    from tools.golden_mesh import make_population
 
-    clients, assignment, test, _latency, program, _ = _make_population(_M, _E)
-    return clients, assignment, test, program
+    return make_population(_M, _E)
 
 
 @pytest.fixture(scope="module")
 def base_run(mesh_pop):
     clients, asn, test, program = mesh_pop
     eng = BatchedSyncEngine(
-        clients, asn, program, test, schedule=_SCHED, seed=0, pipeline="device"
+        clients, asn, program, test, schedule=_SCHED, seed=0
     )
     return eng.run(_ROUNDS, eval_every=1)
 
@@ -295,7 +294,7 @@ def test_scenario_mesh_pipeline_wires_comm_report():
 
     sc = build_scenario("heartbeat", scale=0.02, seed=0, n_test_per_class=10)
     asn = sc.assign("eara-sca").lam
-    res = sc.simulate(asn, 1, engine="sync", pipeline="mesh", seed=0)
+    res = sc.simulate(asn, 1, engine="sync", mesh=1, seed=0)
     assert res.comm_report["devices"] >= 1
     assert "cloud_reduce" in res.comm_report["programs"]
     assert np.isfinite(res.history[-1].test_acc)
@@ -354,7 +353,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np, jax
-from benchmarks.engine_bench import _make_population
+from tools.golden_mesh import make_population
 from repro.core.hfl import HFLSchedule
 from repro.engine import BatchedSyncEngine
 from repro.engine.mesh_sim import MeshSyncEngine
@@ -366,11 +365,11 @@ def params_hash(tree):
         h.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
     return h.hexdigest()
 
-clients, asn, test, _lat, program, _ = _make_population(%(m)d, %(e)d)
+clients, asn, test, program = make_population(%(m)d, %(e)d)
 sched = HFLSchedule(2, 2)
 flat = lambda p: np.concatenate([np.ravel(np.asarray(l)) for l in jax.tree_util.tree_leaves(p)])
-rb = BatchedSyncEngine(clients, asn, program, test, schedule=sched, seed=0,
-                       pipeline="device").run(%(rounds)d, eval_every=1)
+rb = BatchedSyncEngine(clients, asn, program, test, schedule=sched,
+                       seed=0).run(%(rounds)d, eval_every=1)
 out = {}
 for k in (2, 4, 8):
     eng = MeshSyncEngine(clients, asn, program, test, schedule=sched, seed=0, mesh=k)

@@ -1,6 +1,6 @@
 """ClientProgram abstraction tests: registry, per-program FlatPack
-round-trips, store dtype handling, MLP host/device/reference equivalence,
-sequence-program (LM/MoE/Mamba/RWKV) end-to-end smokes and pipeline parity,
+round-trips, store dtype handling, MLP engine/reference equivalence,
+sequence-program (LM/MoE/Mamba/RWKV) end-to-end smokes and reference parity,
 FedSGD single-step semantics + gradient uplink accounting, heterogeneous
 per-client hyperparameters (cohort grouping, mixed-vs-solo bit identity,
 RNG parity), and the async multicast-uplink accounting."""
@@ -184,55 +184,57 @@ def test_mlp_scenario_wiring(mlp_scenario):
 
 
 def test_mlp_host_vs_device_pipeline_equivalence(mlp_scenario):
-    """The acceptance bar: device and host pipelines agree to 1e-6 for the
-    MLP.  The MLP has a single formulation (no conv reassociation), so the
-    only pipeline difference is the segment-mean FedAvg reassociation:
-    after one round the parameter vectors agree to 1e-6 elementwise, and
-    over two rounds (Adam amplifies the 1-ulp aggregation difference) the
-    metrics stay pinned at 1e-6 with params within 2e-5."""
+    """The acceptance bar: the sync engine and the reference simulator
+    agree to 1e-6 for the MLP.  The MLP has a single formulation (no conv
+    reassociation), so what differs is the order of the FedAvg sums
+    (segment mean vs tree mean): after one round the parameter vectors
+    agree to 1e-6 elementwise, and over two rounds (Adam amplifies the
+    1-ulp aggregation difference) the metrics stay pinned at 1e-6 with
+    params within 2e-5.  (The name is kept from when the sync engine had a
+    host pipeline to compare with.)"""
     sc = mlp_scenario
     a = sc.assign("eara-sca")
     one = {
-        pipeline: sc.simulate(a.lam, cloud_rounds=1, seed=11, upp=1.0,
-                              engine="sync", pipeline=pipeline)
-        for pipeline in ("host", "device")
+        engine: sc.simulate(a.lam, cloud_rounds=1, seed=11, upp=1.0, engine=engine)
+        for engine in ("reference", "sync")
     }
     for a_, b_ in zip(
-        jax.tree.leaves(one["host"].final_params),
-        jax.tree.leaves(one["device"].final_params),
+        jax.tree.leaves(one["reference"].final_params),
+        jax.tree.leaves(one["sync"].final_params),
     ):
         np.testing.assert_allclose(np.asarray(a_), np.asarray(b_), atol=1e-6)
     runs = {
-        pipeline: sc.simulate(a.lam, cloud_rounds=2, seed=11, upp=1.0,
-                              engine="sync", pipeline=pipeline)
-        for pipeline in ("host", "device")
+        engine: sc.simulate(a.lam, cloud_rounds=2, seed=11, upp=1.0, engine=engine)
+        for engine in ("reference", "sync")
     }
-    host, dev = runs["host"], runs["device"]
-    for mh, md in zip(host.history, dev.history):
-        assert md.test_acc == pytest.approx(mh.test_acc, abs=1e-6)
-        assert md.mean_local_loss == pytest.approx(mh.mean_local_loss, abs=1e-6)
-    assert dev.accountant.eu_traffic_bits() == host.accountant.eu_traffic_bits()
-    for a_, b_ in zip(jax.tree.leaves(host.final_params), jax.tree.leaves(dev.final_params)):
+    ref, dev = runs["reference"], runs["sync"]
+    for mr, md in zip(ref.history, dev.history):
+        assert md.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
+        assert md.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=1e-6)
+    assert dev.accountant.eu_traffic_bits() == ref.accountant.eu_traffic_bits()
+    for a_, b_ in zip(jax.tree.leaves(ref.final_params), jax.tree.leaves(dev.final_params)):
         np.testing.assert_allclose(np.asarray(a_), np.asarray(b_), atol=2e-5)
 
 
 def test_mlp_host_vs_device_stress_schedule(mlp_scenario):
     """Multi-epoch schedule + partial participation: Adam amplifies the
-    segment-mean reassociation round over round (same effect the CNN tests
-    document), so params track to float tolerance and metrics stay pinned."""
+    FedAvg reassociation round over round (same effect the CNN tests
+    document), so params track to float tolerance and metrics stay pinned.
+    The sync engine is held to the reference simulator; the name is kept
+    from when it had a host pipeline to compare with."""
     sc = mlp_scenario
     a = sc.assign("eara-sca")
     runs = {
-        pipeline: sc.simulate(a.lam, cloud_rounds=2, schedule=HFLSchedule(2, 2),
-                              seed=11, upp=0.8, engine="sync", pipeline=pipeline)
-        for pipeline in ("host", "device")
+        engine: sc.simulate(a.lam, cloud_rounds=2, schedule=HFLSchedule(2, 2),
+                            seed=11, upp=0.8, engine=engine)
+        for engine in ("reference", "sync")
     }
-    host, dev = runs["host"], runs["device"]
-    for mh, md in zip(host.history, dev.history):
-        assert md.test_acc == pytest.approx(mh.test_acc, abs=1e-6)
-        assert md.mean_local_loss == pytest.approx(mh.mean_local_loss, abs=1e-5)
-    assert dev.accountant.eu_traffic_bits() == host.accountant.eu_traffic_bits()
-    for a_, b_ in zip(jax.tree.leaves(host.final_params), jax.tree.leaves(dev.final_params)):
+    ref, dev = runs["reference"], runs["sync"]
+    for mr, md in zip(ref.history, dev.history):
+        assert md.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
+        assert md.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=1e-5)
+    assert dev.accountant.eu_traffic_bits() == ref.accountant.eu_traffic_bits()
+    for a_, b_ in zip(jax.tree.leaves(ref.final_params), jax.tree.leaves(dev.final_params)):
         np.testing.assert_allclose(np.asarray(a_), np.asarray(b_), atol=1e-3)
 
 
@@ -308,22 +310,21 @@ def _tiny_population(dual: bool):
     return program, clients, test, asn
 
 
-# -- MoE / Mamba / RWKV: end-to-end on every pipeline -------------------------
+# -- MoE / Mamba / RWKV: end-to-end on every engine ---------------------------
 @pytest.fixture(scope="module", params=("moe", "mamba", "rwkv"))
 def seq_model_runs(request):
     """One tiny topic-skewed scenario per sequence model, simulated 2 cloud
-    rounds on sync-device, sync-host, and async.  Module-scoped per model so
-    the (compile-heavy) runs happen once and every assertion reuses them."""
+    rounds on the sync engine, the reference simulator, and async.
+    Module-scoped per model so the (compile-heavy) runs happen once and
+    every assertion reuses them."""
     model = request.param
     sc = build_scenario(model=model, scale=0.04, seed=0, n_test_per_class=6,
                         lm_eus=5, lm_edges=2, lm_topics=3, lm_seq_len=16,
                         lm_vocab=64)
     a = sc.assign("eara-sca")
     runs = {
-        "device": sc.simulate(a.lam, cloud_rounds=2, seed=3, engine="sync",
-                              pipeline="device"),
-        "host": sc.simulate(a.lam, cloud_rounds=2, seed=3, engine="sync",
-                            pipeline="host"),
+        "device": sc.simulate(a.lam, cloud_rounds=2, seed=3, engine="sync"),
+        "reference": sc.simulate(a.lam, cloud_rounds=2, seed=3, engine="reference"),
         "async": sc.simulate(a.lam, cloud_rounds=2, seed=3, engine="async"),
     }
     return model, sc, runs
@@ -339,10 +340,11 @@ def test_seq_program_scenario_wiring(seq_model_runs):
     assert (frac > 0.5).all()
 
 
-@pytest.mark.parametrize("engine", ["device", "host", "async"])
+@pytest.mark.parametrize("engine", ["device", "reference", "async"])
 def test_seq_program_trains_two_rounds(seq_model_runs, engine):
-    """Acceptance bar: >= 2 cloud rounds on both sync pipelines AND the
-    async engine with finite, non-degenerate loss for every new program."""
+    """Acceptance bar: >= 2 cloud rounds on the sync engine, the reference
+    simulator AND the async engine with finite, non-degenerate loss for
+    every new program."""
     model, sc, runs = seq_model_runs
     res = runs[engine]
     assert len(res.history) == 2
@@ -353,15 +355,16 @@ def test_seq_program_trains_two_rounds(seq_model_runs, engine):
 
 
 def test_seq_program_host_vs_device_parity(seq_model_runs):
-    """The sequence programs have a single formulation, so host and device
-    pipelines share every jitted epoch computation — metrics must agree to
-    float tolerance (same bar as the MLP parity tests)."""
+    """The sequence programs have a single formulation, so the sync engine
+    and the reference simulator differ only in summation order — metrics
+    must agree to float tolerance (same bar as the MLP parity tests).  The
+    name is kept from when the sync engine had a host pipeline."""
     _, _, runs = seq_model_runs
-    host, dev = runs["host"], runs["device"]
-    for mh, md in zip(host.history, dev.history):
-        assert md.test_acc == pytest.approx(mh.test_acc, abs=1e-6)
-        assert md.mean_local_loss == pytest.approx(mh.mean_local_loss, abs=1e-5)
-    assert dev.accountant.eu_traffic_bits() == host.accountant.eu_traffic_bits()
+    ref, dev = runs["reference"], runs["device"]
+    for mr, md in zip(ref.history, dev.history):
+        assert md.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
+        assert md.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=1e-5)
+    assert dev.accountant.eu_traffic_bits() == ref.accountant.eu_traffic_bits()
 
 
 # -- FedSGD: single-step semantics + gradient uplink accounting ---------------
@@ -538,7 +541,7 @@ def test_hetero_explicit_defaults_match_homogeneous_rng_parity():
 
 
 def test_hetero_hparams_engine_matches_reference():
-    """Two distinct (lr, local-epochs) groups: the batched engines must
+    """Two distinct (lr, local-epochs) groups: the batched engine must
     reproduce the reference simulator's trajectory (the reference trains
     each client sequentially with its own hyperparameters, so this parity
     IS the per-group-correctness guarantee end to end)."""
@@ -550,16 +553,11 @@ def test_hetero_hparams_engine_matches_reference():
     assert {(c.lr, c.local_epochs) for c in sc.clients} == {(1e-3, 1), (5e-4, 2)}
     a = sc.assign("eara-sca")
     ref = sc.simulate(a.lam, cloud_rounds=2, seed=0)
-    runs = {
-        pipeline: sc.simulate(a.lam, cloud_rounds=2, seed=0, engine="sync",
-                              pipeline=pipeline)
-        for pipeline in ("host", "device")
-    }
-    for res in runs.values():
-        for mr, me in zip(ref.history, res.history):
-            assert me.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
-            assert me.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=1e-5)
-        assert res.accountant.eu_traffic_bits() == ref.accountant.eu_traffic_bits()
+    res = sc.simulate(a.lam, cloud_rounds=2, seed=0, engine="sync")
+    for mr, me in zip(ref.history, res.history):
+        assert me.test_acc == pytest.approx(mr.test_acc, abs=1e-6)
+        assert me.mean_local_loss == pytest.approx(mr.mean_local_loss, abs=1e-5)
+    assert res.accountant.eu_traffic_bits() == ref.accountant.eu_traffic_bits()
 
 
 # -- async accounting: multicast per dispatch --------------------------------

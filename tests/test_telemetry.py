@@ -154,7 +154,7 @@ def _traj_fields(res):
 
 
 @pytest.mark.parametrize("engine,kw", [
-    ("sync", {"pipeline": "device"}),
+    ("sync", {}),
     ("async", {}),
 ])
 def test_bit_identical_on_vs_off(scenario, assignment, engine, kw):
@@ -191,7 +191,7 @@ def hetero_scenario():
 
 @pytest.mark.parametrize("engine,kw,train_span", [
     ("reference", {}, "local_train"),
-    ("sync", {"pipeline": "device"}, "cohort_epoch"),
+    ("sync", {}, "cohort_epoch"),
     ("async", {}, "cohort_epoch"),
 ])
 def test_engine_artifacts(tmp_path, hetero_scenario, engine, kw, train_span):

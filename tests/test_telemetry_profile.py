@@ -34,7 +34,7 @@ def assignment(scenario):
 def _engine(scenario, assignment, tel):
     return BatchedSyncEngine(
         scenario.clients, assignment, scenario.program, scenario.test,
-        schedule=HFLSchedule(1, EDGE_ROUNDS), seed=0, pipeline="device", telemetry=tel,
+        schedule=HFLSchedule(1, EDGE_ROUNDS), seed=0, telemetry=tel,
     )
 
 
@@ -197,10 +197,10 @@ def test_gc_callback_lives_only_while_run_runs(scenario, assignment):
 
 def test_bit_identical_with_profile_on(tmp_path, scenario, assignment):
     """A profiled, telemetry-on run keeps the telemetry-off trajectory."""
-    r_off = scenario.simulate(assignment, 2, engine="sync", seed=0, pipeline="device")
+    r_off = scenario.simulate(assignment, 2, engine="sync", seed=0)
     runs = []
     _profiled(tmp_path, lambda: runs.append(scenario.simulate(
-        assignment, 2, engine="sync", seed=0, pipeline="device",
+        assignment, 2, engine="sync", seed=0,
         telemetry=Telemetry(profile=True))))
     (r_on,) = runs
     assert r_on.telemetry is not None

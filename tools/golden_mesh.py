@@ -1,6 +1,6 @@
 """Regenerate the mesh-trajectory pins (tests/golden/mesh_trajectory.json).
 
-Runs the mesh-harness population (``benchmarks.engine_bench``'s micro-CNN,
+Runs the mesh-harness population (``make_population``: a micro 1-D CNN,
 M=24 over 8 edges, T=2, 2 cloud rounds) through ``MeshSyncEngine`` on every
 harness mesh size {1, 2, 4, 8} and records the accuracy history plus a
 sha256 over the final parameter bytes per size.  ``tests/test_hfl_mesh.py``
@@ -8,30 +8,50 @@ asserts future code reproduces these exactly on the same jax version —
 cross-mesh parity keeps accuracies identical across sizes, but the cloud
 psum's float association makes each size's parameter BYTES its own pin.
 
-Must run before jax is imported elsewhere (it forces 8 virtual devices):
+Run as a script it forces 8 virtual devices before jax starts a backend:
 
     PYTHONPATH=src:. python tools/golden_mesh.py
 """
 from __future__ import annotations
 
+import json
 import os
+import sys
 
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
-)
+import jax
+import numpy as np
 
-import json  # noqa: E402
-import sys  # noqa: E402
 
-import jax  # noqa: E402
+def make_population(m: int, n_edges: int):
+    """M micro-CNN clients with small imbalanced shards, round-robin over
+    ``n_edges`` edges.  Returns ``(clients, assignment, test, program)``;
+    shared by the mesh tests, these pins and ``benchmarks/distributed_bench.py``."""
+    from repro.data.partition import split_dataset_by_counts
+    from repro.data.synthetic_health import heartbeat_like
+    from repro.federated.client import FLClient
+    from repro.federated.programs import CNNProgram
+    from repro.models.cnn1d import CNNConfig
+
+    cfg = CNNConfig(in_channels=1, n_classes=5, seq_len=64, c1=8, c2=8, hidden=16)
+    program = CNNProgram(cfg)
+    rng = np.random.default_rng(0)
+    counts = rng.integers(1, 3, (m, cfg.n_classes))
+    train = heartbeat_like(rng, counts.sum(axis=0))
+    train.x = train.x[:, : cfg.seq_len, : cfg.in_channels]
+    shards = split_dataset_by_counts(rng, train, counts)
+    test = heartbeat_like(rng, np.full(cfg.n_classes, 10))
+    test.x = test.x[:, : cfg.seq_len, : cfg.in_channels]
+    clients = [FLClient(i, shards[i], program) for i in range(m)]
+    assignment = np.zeros((m, n_edges))
+    assignment[np.arange(m), np.arange(m) % n_edges] = 1.0
+    return clients, assignment, test, program
 
 
 def mesh_runs(ks=(1, 2, 4, 8)):
-    from benchmarks.engine_bench import _make_population
     from repro.core.hfl import HFLSchedule
     from repro.engine.mesh_sim import MeshSyncEngine
 
-    clients, assignment, test, _latency, program, _ = _make_population(24, 8)
+    clients, assignment, test, program = make_population(24, 8)
     out = {}
     for k in ks:
         if k > jax.device_count():
@@ -50,7 +70,7 @@ def main() -> None:
     out = {
         "jax": jax.__version__,
         "backend": jax.default_backend(),
-        "scenario": "engine_bench micro-CNN m=24 e=8 T=2 seed=0, 2 cloud rounds",
+        "scenario": "make_population micro-CNN m=24 e=8 T=2 seed=0, 2 cloud rounds",
         "runs": {},
     }
     for name, res in mesh_runs().items():
@@ -70,4 +90,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
+    )
     sys.exit(main())

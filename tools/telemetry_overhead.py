@@ -50,7 +50,7 @@ def build(seed: int):
     tels = {"off": None, "on": Telemetry(), "profile": Telemetry(profile=True)}
     return {mode: BatchedSyncEngine(sc.clients, lam, sc.program, sc.test,
                                     schedule=HFLSchedule(1, 4), seed=seed, upp=1.0,
-                                    pipeline="device", telemetry=tel)
+                                    telemetry=tel)
             for mode, tel in tels.items()}
 
 
